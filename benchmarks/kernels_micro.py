@@ -85,10 +85,11 @@ def run(print_fn=print, smoke: bool = False, json_path: Optional[str] = None):
     print_fn(f"attn_dense_gather_{Bd}x{Sd//1024}k,{us_dense:.0f},"
              f"{dense_bytes/(us_dense*1e-6)/1e9:.1f}GB/s_cpu")
 
-    # paged path: pool view + identity tables bounded to the live context
+    # paged path: head-major (pages, KV, page, d) pool view + identity
+    # tables bounded to the live context
     pps = Sd // page
-    pool_k = kd.reshape(Bd * pps, page, KV, d)
-    pool_v = vd.reshape(Bd * pps, page, KV, d)
+    pool_k = kd.reshape(Bd * pps, page, KV, d).transpose(0, 2, 1, 3)
+    pool_v = vd.reshape(Bd * pps, page, KV, d).transpose(0, 2, 1, 3)
     nb = int(-(-int(lens_np.max()) // page))
     tables = jnp.asarray(
         (np.arange(Bd)[:, None] * pps + np.arange(nb)[None, :]).astype(np.int32))
@@ -140,7 +141,8 @@ def run(print_fn=print, smoke: bool = False, json_path: Optional[str] = None):
     # scatter parity: the table-routed write lands where the dense write
     # would, page-permutation notwithstanding
     got = np.asarray(f_paged_w(pool_kw, vals))[np.asarray(perm_w).argsort()]
-    want = np.asarray(f_dense_w(kd, vals)).reshape(Bd * pps, page, KV, d)
+    want = np.asarray(f_dense_w(kd, vals)).reshape(
+        Bd * pps, page, KV, d).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(got, want, rtol=0, atol=0)
 
     # ------------------------------------------------------------------

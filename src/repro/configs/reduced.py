@@ -18,6 +18,22 @@ def dropless(cfg: ModelConfig) -> ModelConfig:
     return dataclasses.replace(cfg, moe_capacity_factor=float(cfg.n_experts))
 
 
+def cut_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """Depth-only cut: the first ``n_layers`` layers at the published widths
+    (every width, head count and the vocabulary are kept). ``n_layers``
+    must keep the prefix layers and whole scan periods."""
+    body = n_layers - cfg.n_prefix_layers
+    if not 0 < n_layers <= cfg.n_layers or body < 0 or body % cfg.scan_period:
+        raise ValueError(
+            f"{cfg.name}: cannot cut {cfg.n_layers} layers to {n_layers} "
+            f"({cfg.n_prefix_layers} prefix + periods of {cfg.scan_period})")
+    if n_layers == cfg.n_layers:
+        return cfg
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}-{n_layers}L", n_layers=n_layers,
+        layer_specs=cfg.layer_specs[:n_layers])
+
+
 def reduce_config(cfg: ModelConfig, *, periods: int = 1, vocab: int = 256) -> ModelConfig:
     n_layers = cfg.n_prefix_layers + cfg.scan_period * min(cfg.n_periods, periods)
     layer_specs = cfg.layer_specs[:n_layers]

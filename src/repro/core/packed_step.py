@@ -19,9 +19,10 @@ Two attention realizations:
     row's full padded KV extent: O(N * S_max) bytes/FLOPs regardless of real
     lengths. Kept as reference/fallback.
   * ragged paged (``paged`` given) — KV lives in a *physical page pool*
-    (n_pages, page_size, ...): both reads AND this step's writes route
-    through the engine's block-table mirror, which carries the allocator's
-    real (arbitrary, non-contiguous) page ids. A row's token at position p
+    (n_pages, KV, page_size, d) (MLA latents: (n_pages, page_size, L)):
+    both reads AND this step's writes route through the engine's
+    block-table mirror, which carries the allocator's real (arbitrary,
+    non-contiguous) page ids. A row's token at position p
     scatters into page ``table[slot, p // page]`` offset ``p % page``, and
     attention reads only the pages the row's table names, bounded to the
     live context (tables arrive sliced to ``nb = ceil(max_live_len /
@@ -53,9 +54,10 @@ class PagedView:
     """Ragged paged-attention inputs for one packed step over a *physical*
     page pool.
 
-    The cache arrays are (n_pages, page_size, ...) pools — there is no dense
-    slot axis. ``block_tables`` is the engine's device mirror of the
-    allocator's block tables — one row per scheduler slot (incl. the scratch
+    The cache arrays are head-major (n_pages, KV, page_size, d) pools
+    (MLA latents: (n_pages, page_size, L)) — there is no dense slot axis.
+    ``block_tables`` is the engine's device mirror of the allocator's block
+    tables — one row per scheduler slot (incl. the scratch
     slot), carrying the allocator's **actual** page ids, already sliced to
     ``nb`` columns where ``nb * page_size`` covers the longest live context
     this step. Dead entries point at the scratch page, so every id is a
@@ -89,10 +91,16 @@ class PagedView:
         """Write each row's new K/V through the block table: token at
         logical position p of slot s lands in physical page
         ``table[s, p // page]`` at offset ``p % page``. The scheduler grew
-        the tables at plan time, so the target pages always exist."""
+        the tables at plan time, so the target pages always exist.
+
+        GQA values (N, KV, d) go into a head-major (P, KV, page, d) pool;
+        MLA latent values (N, L) into a (P, page, L) pool, which has no
+        head axis."""
         pages = self.block_tables[slots, positions // self.page_size]
-        return pool.at[pages, positions % self.page_size].set(
-            values.astype(pool.dtype))
+        offsets = positions % self.page_size
+        if values.ndim == 3:
+            return pool.at[pages, :, offsets].set(values.astype(pool.dtype))
+        return pool.at[pages, offsets].set(values.astype(pool.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -256,8 +264,13 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
         )
         new_prefix.append(nc)
 
-    def body(x, xs):
-        p_period, cache_period = xs
+    # the layer-stacked cache rides the scan CARRY (not xs -> ys): each
+    # period reads its slice and writes it back in place, so a donated cache
+    # is updated without a second full-size copy of the pool
+    def body(carry, xs):
+        x, periods = carry
+        p_period, layer = xs
+        cache_period = jax.tree.map(lambda c: c[layer], periods)
         new_cache = {}
         for i in range(cfg.scan_period):
             x, nc = _packed_layer(
@@ -265,11 +278,15 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
                 cache_period[str(i)], model.inv_freq, paged=paged,
             )
             new_cache[str(i)] = nc
-        return x, new_cache
+        periods = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
+            periods, new_cache)
+        return (x, periods), None
 
     if cfg.n_periods:
-        x, new_periods = jax.lax.scan(
-            body, x, (params["stack"]["periods"], cache["periods"])
+        (x, new_periods), _ = jax.lax.scan(
+            body, (x, cache["periods"]),
+            (params["stack"]["periods"], jnp.arange(cfg.n_periods)),
         )
     else:
         new_periods = cache["periods"]

@@ -23,19 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _MESH: Optional[Mesh] = None
 
 
-def shard_map(body, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: jax >= 0.5 exposes ``jax.shard_map``
-    with ``check_vma``; jax 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with the same semantics under ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def activation_mesh() -> Optional[Mesh]:
     return _MESH
 

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.ctx import shard_map as _shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable,  # (stage_params, x (mb, ...)) -> (mb, ...)
@@ -75,7 +73,7 @@ def pipeline_apply(
         lambda l: P(*([axis] + [None] * (len(l.shape) - 1))), stage_params
     )
     other = tuple(a for a in mesh.axis_names if a != axis)
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(in_param_specs, P(*([None] * x.ndim))),

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.ctx import shard_map as _shard_map
 
 NEG_INF = -1.0e30
 
@@ -77,7 +76,7 @@ def sp_decode_attention(
         o = jax.lax.psum(o_i * scale_i[..., None], axis) / jnp.maximum(l, 1e-37)[..., None]
         return o.reshape(B, 1, H, d).astype(q.dtype)
 
-    return _shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(b_ax, None, None, None), P(b_ax, axis, None, None),

@@ -68,17 +68,16 @@ def paged_attention_rows(
     q, k_pages, v_pages, lengths, block_tables,
     *, window=None, softcap=None, use_kernel=False, interpret=False,
 ):
-    """Packed-row layout wrapper: q (N,H,d) + page pool (P,page,KV,d),
-    per-row lengths (N,) and block tables (N,nb) -> (N,H,d)."""
-    N, H, d = q.shape
-    KV = k_pages.shape[2]
-    G = H // KV
-    qg = q.reshape(N, KV, G, d)
-    o = _pa.ragged_paged_attention(
-        qg, k_pages, v_pages, lengths.astype(jnp.int32), block_tables,
-        window=window, softcap=softcap, use_kernel=use_kernel, interpret=interpret,
+    """Per-row layout wrapper: q (N,H,d) + head-major page pool
+    (P,KV,page,d), per-row lengths (N,) and block tables (N,nb) -> (N,H,d).
+    Each row is a one-token segment of the mixed-batch kernel (the decode
+    case), attending ``lengths[n]`` keys."""
+    N = q.shape[0]
+    return mixed_attention_rows(
+        q, k_pages, v_pages, jnp.arange(N + 1, dtype=jnp.int32), lengths,
+        block_tables, qb=1, window=window, softcap=softcap,
+        use_kernel=use_kernel, interpret=interpret,
     )
-    return o.reshape(N, H, d)
 
 
 def mixed_attention_rows(
@@ -87,11 +86,12 @@ def mixed_attention_rows(
 ):
     """Packed mixed-batch layout wrapper: q (N,H,d) rows laid out by segment
     (cu_q_lens (S+1,) row offsets; a decode row is a 1-token segment, a
-    prefill chunk a longer one), per-SEGMENT kv extents (S,) and block
-    tables (S,nb) -> (N,H,d). ``qb`` is the static q-block (pow2 >= the
-    longest segment) the kernel tiles queries with."""
+    prefill chunk a longer one), head-major page pool (P,KV,page,d),
+    per-SEGMENT kv extents (S,) and block tables (S,nb) -> (N,H,d). ``qb``
+    is the static q-block (pow2 >= the longest segment) the kernel tiles
+    queries with."""
     N, H, d = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     G = H // KV
     qg = q.reshape(N, KV, G, d)
     o = _pa.ragged_mixed_attention(

@@ -1,37 +1,45 @@
-"""Ragged block-table (paged) attention Pallas kernel for the packed step.
+"""Mixed-batch ragged block-table (paged) attention Pallas kernel.
 
 The serving engine's packed step mixes decode tokens and prefill-chunk tokens
-in one flat row set; every row attends over its *own* context prefix. The
-dense-gather realization (`cache[slots]` in core/packed_step.py) reads
-O(N * S_max) KV bytes regardless of the rows' actual lengths. This kernel is
-the vLLM-style paged counterpart: KV lives in a page pool, each row names its
-pages through a block table, and per-row `lengths` arrive via scalar prefetch
-so whole out-of-range pages are skipped — attention cost scales with the
-tokens a row actually owns, not with the padded cache extent.
+in one flat row set; every row attends over its *own* context prefix. This
+kernel is the vLLM-style paged realization: KV lives in a page pool, each
+segment (a prefill chunk or a single decode row) names its pages through a
+block table, and per-segment lengths arrive via scalar prefetch so whole
+out-of-range pages are skipped — attention cost scales with the tokens a
+segment actually owns, not with the padded cache extent.
 
-Layouts (one flat row per query token, grouped-query heads):
+Layouts (grouped-query heads, head-major so the TPU tiles see whole pages):
   q:            (N, KV, G, d)      one query per packed row
-  k/v_pages:    (P, page, KV, d)   page pool; the engine allocates KV in this
+  k/v_pages:    (P, KV, page, d)   page pool; the engine allocates KV in this
                                    shape directly (physically paged — pages
                                    are relocatable, ids arbitrary) and the
                                    tables carry the block allocator's real
-                                   page ids
-  lengths:      (N,) int32         keys row n may attend (<= nb * page)
-  block_tables: (N, nb) int32      per-row page ids, logical order; entries
-                                   past ceil(length/page) must still be valid
-                                   page ids (the engine points them at a
-                                   scratch page) because index maps run even
-                                   for skipped grid steps
+                                   page ids. KV sits outside the last two
+                                   axes, so one grid step's block is one
+                                   head's (page, d) tile — the shape Mosaic
+                                   requires (second-minor divisible by 8 or
+                                   the whole axis).
+  cu_q_lens:    (S+1,) int32       row offsets of each segment
+  kv_lens:      (S,) int32         keys the segment's last row attends
+  block_tables: (S, nb) int32      per-segment page ids, logical order;
+                                   entries past ceil(kv_len/page) must still
+                                   be valid page ids (the engine points them
+                                   at a scratch page) because index maps run
+                                   even for skipped grid steps
 
-Grid is (N, KV, nb); the last dimension streams pages with Mosaic's software
-pipeline double-buffering page ib+1's DMA under page ib's compute, exactly
-like kernels/decode_attention.py — plus the block-table indirection in the
-index map (scalar-prefetched, so the DMA address is known before the grid
-step runs). `pl.when` guards skip compute AND the online-softmax update for
-pages past a row's length (and, with `window`, pages wholly below it).
+Grid is (S, KV, nb); the last dimension streams pages with Mosaic's software
+pipeline double-buffering page ib+1's DMA under page ib's compute, with the
+block-table indirection in the index map (scalar-prefetched, so the DMA
+address is known before the grid step runs). `pl.when` guards skip compute
+AND the online-softmax update for pages past a segment's length (and, with
+`window`, pages wholly below it).
 
-`ragged_paged_attention` dispatches to the kernel (TPU / interpret) or to the
-pure-jnp oracle `kernels.ref.paged_attention_ref` (CPU serving + CI).
+The block tables are scalar-prefetched into SMEM, which bounds (S, nb): at
+S=40 segments, nb=4096 (64K tokens at page 16) compiles for a v5e and
+nb=8192 exceeds its 1 MiB SMEM.
+
+`ragged_mixed_attention` dispatches to the kernel (TPU / interpret) or to
+the pure-jnp oracle `kernels.ref.mixed_attention_ref` (CPU serving + CI).
 """
 from __future__ import annotations
 
@@ -42,8 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -1.0e30
 LANES = 128
@@ -57,143 +63,6 @@ def tokens_touched(lengths, page: int) -> int:
     from repro.sim.opcost import kv_tokens_touched
 
     return kv_tokens_touched(lengths, page)
-
-
-def _paged_kernel(
-    lengths_ref,  # scalar prefetch (N,)
-    tables_ref,  # scalar prefetch (N, nb)
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale, window, softcap_val, page,
-):
-    n = pl.program_id(0)
-    ib = pl.program_id(2)
-    nb = pl.num_programs(2)
-    length = lengths_ref[n]
-
-    @pl.when(ib == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    k_start = ib * page  # logical key position of this page's first slot
-    run = k_start < length
-    if window is not None:
-        run &= k_start + page > length - window
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (G, page)
-        if softcap_val is not None:
-            s = softcap_val * jnp.tanh(s / softcap_val)
-
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < length
-        if window is not None:
-            mask &= k_pos > length - 1 - window  # query position = length-1
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, 1, keepdims=True), l_ref.shape)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(ib == nb - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "softcap", "interpret"))
-def paged_attention(
-    q, k_pages, v_pages, lengths, block_tables,
-    *,
-    window: Optional[int] = None,
-    softcap: Optional[float] = None,
-    interpret: bool = False,
-):
-    """q: (N, KV, G, d); k/v_pages: (P, page, KV, d); lengths: (N,);
-    block_tables: (N, nb) -> (N, KV, G, d)."""
-    N, KV, G, d = q.shape
-    page = k_pages.shape[1]
-    nb = block_tables.shape[1]
-    scale = 1.0 / d**0.5
-    grid = (N, KV, nb)
-
-    kernel = functools.partial(
-        _paged_kernel, scale=scale, window=window, softcap_val=softcap, page=page
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            # index maps receive the scalar-prefetch refs as trailing args;
-            # the k/v maps read the block table — the paged indirection
-            pl.BlockSpec((1, 1, G, d), lambda n, h, ib, lens, tabs: (n, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda n, h, ib, lens, tabs: (tabs[n, ib], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda n, h, ib, lens, tabs: (tabs[n, ib], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, d), lambda n, h, ib, lens, tabs: (n, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, d), jnp.float32),
-            pltpu.VMEM((G, LANES), jnp.float32),
-            pltpu.VMEM((G, LANES), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="paged_attention",
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def ragged_paged_attention(
-    q, k_pages, v_pages, lengths, block_tables,
-    *,
-    window: Optional[int] = None,
-    softcap: Optional[float] = None,
-    use_kernel: bool = False,
-    interpret: bool = False,
-):
-    """Dispatch: Pallas kernel on TPU (or interpret mode), jnp oracle on CPU.
-
-    The oracle gathers exactly the pages the tables name (O(N * nb * page)
-    bytes — the caller bounds nb to the live context, not S_max), so even the
-    fallback's attention cost scales with real tokens.
-    """
-    if use_kernel or interpret:
-        return paged_attention(
-            q, k_pages, v_pages, lengths, block_tables,
-            window=window, softcap=softcap, interpret=interpret,
-        )
-    from repro.kernels.ref import paged_attention_ref
-
-    return paged_attention_ref(
-        q, k_pages, v_pages, lengths, block_tables, window=window, softcap=softcap
-    )
-
-
-# ---------------------------------------------------------------------------
-# unified mixed-batch (prefill chunks + decode rows) ragged attention
-# ---------------------------------------------------------------------------
 
 
 def _mixed_kernel(
@@ -225,9 +94,9 @@ def _mixed_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0].astype(jnp.float32)  # (QB*G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)  # (QB*G, d)
+        k = k_ref[0].astype(jnp.float32)  # (page, d)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (QB*G, page)
@@ -259,7 +128,7 @@ def _mixed_kernel(
     @pl.when(ib == nb - 1)
     def _finalize():
         l = l_ref[:, :1]
-        o_ref[0, :, 0] = (acc_ref[...] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -279,16 +148,17 @@ def mixed_paged_attention(
     per chunk instead of once per token.
 
     q: (N, KV, G, d) flat packed rows, segments contiguous in cu_q_lens
-    order; cu_q_lens: (S+1,) int32 row offsets; kv_lens: (S,) int32 keys the
-    segment's last row attends; block_tables: (S, nb) int32 per-segment page
-    ids. ``qb`` is the static q-block row count — a pow2 bucket of the
-    longest segment (the engine buckets it alongside nb and S so the jit
-    cache stays bounded). Rows past cu_q_lens[-1] are padding and come back
-    zero; segments with q_len == 0 are skipped entirely.
+    order; k/v_pages: (P, KV, page, d); cu_q_lens: (S+1,) int32 row offsets;
+    kv_lens: (S,) int32 keys the segment's last row attends; block_tables:
+    (S, nb) int32 per-segment page ids. ``qb`` is the static q-block row
+    count — a pow2 bucket of the longest segment (the engine buckets it
+    alongside nb and S so the jit cache stays bounded). Rows past
+    cu_q_lens[-1] are padding and come back zero; segments with q_len == 0
+    are skipped entirely.
     """
     N, KV, G, d = q.shape
     S = kv_lens.shape[0]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     nb = block_tables.shape[1]
     scale = 1.0 / d**0.5
 
@@ -298,14 +168,14 @@ def mixed_paged_attention(
     seg = jnp.searchsorted(cu, row, side="right") - 1  # S for padding rows
     j = row - cu[jnp.clip(seg, 0, S)]
 
-    # per-segment q-block layout (S, qb*G, KV, d): segment s's chunk-local
-    # token j lands in rows [j*G, (j+1)*G); padding rows scatter into the
-    # throwaway S-th slot (dropped below), tail rows past qb are dropped by
-    # the scatter's out-of-bounds semantics
-    qt = q.transpose(0, 2, 1, 3)  # (N, G, KV, d)
-    q_seg = jnp.zeros((S + 1, qb, G, KV, d), q.dtype)
-    q_seg = q_seg.at[jnp.clip(seg, 0, S), j].set(qt)
-    q_seg = q_seg[:S].reshape(S, qb * G, KV, d)
+    # per-segment head-major q-block (S, KV, qb*G, d): segment s's
+    # chunk-local token j lands in rows [j*G, (j+1)*G) of every head's
+    # block; padding rows scatter into the throwaway S-th slot (dropped
+    # below), tail rows past qb are dropped by the scatter's out-of-bounds
+    # semantics
+    q_seg = jnp.zeros((S + 1, qb, KV, G, d), q.dtype)
+    q_seg = q_seg.at[jnp.clip(seg, 0, S), j].set(q)
+    q_seg = q_seg[:S].transpose(0, 2, 1, 3, 4).reshape(S, KV, qb * G, d)
 
     kernel = functools.partial(
         _mixed_kernel, scale=scale, window=window, softcap_val=softcap,
@@ -315,15 +185,15 @@ def mixed_paged_attention(
         num_scalar_prefetch=3,
         grid=(S, KV, nb),
         in_specs=[
-            pl.BlockSpec((1, qb * G, 1, d),
-                         lambda s, h, ib, qls, kls, tabs: (s, 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda s, h, ib, qls, kls, tabs: (tabs[s, ib], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda s, h, ib, qls, kls, tabs: (tabs[s, ib], 0, h, 0)),
+            pl.BlockSpec((1, None, qb * G, d),
+                         lambda s, h, ib, qls, kls, tabs: (s, h, 0, 0)),
+            pl.BlockSpec((1, None, page, d),
+                         lambda s, h, ib, qls, kls, tabs: (tabs[s, ib], h, 0, 0)),
+            pl.BlockSpec((1, None, page, d),
+                         lambda s, h, ib, qls, kls, tabs: (tabs[s, ib], h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, qb * G, 1, d),
-                               lambda s, h, ib, qls, kls, tabs: (s, 0, h, 0)),
+        out_specs=pl.BlockSpec((1, None, qb * G, d),
+                               lambda s, h, ib, qls, kls, tabs: (s, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((qb * G, d), jnp.float32),
             pltpu.VMEM((qb * G, LANES), jnp.float32),
@@ -333,8 +203,8 @@ def mixed_paged_attention(
     o_seg = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, qb * G, KV, d), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((S, KV, qb * G, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -345,9 +215,8 @@ def mixed_paged_attention(
     )
     # back to flat rows: padding rows clamp into some segment's tail and are
     # discarded by the caller, like every packed padding row
-    o_r = o_seg.reshape(S, qb, G, KV, d)
-    o = o_r[jnp.clip(seg, 0, S - 1), jnp.clip(j, 0, qb - 1)]
-    return o.transpose(0, 2, 1, 3)  # (N, KV, G, d)
+    o_r = o_seg.reshape(S, KV, qb, G, d)
+    return o_r[jnp.clip(seg, 0, S - 1), :, jnp.clip(j, 0, qb - 1)]  # (N, KV, G, d)
 
 
 def ragged_mixed_attention(

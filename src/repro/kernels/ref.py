@@ -57,20 +57,24 @@ def paged_attention_ref(
     q, k_pages, v_pages, lengths, block_tables,
     *, window: Optional[int] = None, softcap: Optional[float] = None,
 ):
-    """q: (N,KV,G,d); k/v_pages: (P,page,KV,d); lengths (N,);
-    block_tables (N,nb) -> (N,KV,G,d).
+    """q: (N,KV,G,d); k/v_pages: (P,KV,page,d) head-major pool; lengths
+    (N,); block_tables (N,nb) -> (N,KV,G,d).
 
     Gathers each row's pages in block-table order (logical key position
     ib*page + offset), masks keys at/above the row's length, fp32 softmax.
     The last valid page may be partially filled; entries past
     ceil(length/page) are never read into the result (fully masked)."""
     N, KV, G, d = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     nb = block_tables.shape[1]
     S = nb * page
-    kc = k_pages[block_tables].reshape(N, S, KV, d).astype(jnp.float32)
-    vc = v_pages[block_tables].reshape(N, S, KV, d).astype(jnp.float32)
-    s = jnp.einsum("nkgd,nskd->nkgs", q.astype(jnp.float32), kc) / d**0.5
+
+    def rows(pages):  # (N, nb, KV, page, d) -> (N, KV, nb*page, d)
+        return (pages[block_tables].transpose(0, 2, 1, 3, 4)
+                .reshape(N, KV, S, d).astype(jnp.float32))
+
+    kc, vc = rows(k_pages), rows(v_pages)
+    s = jnp.einsum("nkgd,nksd->nkgs", q.astype(jnp.float32), kc) / d**0.5
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
     k_pos = jnp.arange(S)[None, :]
@@ -79,7 +83,7 @@ def paged_attention_ref(
         mask &= k_pos > (lengths[:, None] - 1) - window  # query pos = length-1
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("nkgs,nskd->nkgd", p, vc).astype(q.dtype)
+    return jnp.einsum("nkgs,nksd->nkgd", p, vc).astype(q.dtype)
 
 
 def mixed_attention_ref(

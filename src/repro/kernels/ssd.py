@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 DEFAULT_CHUNK = 128
 
 
@@ -105,7 +103,7 @@ def ssd_chunk_scan(x, a, dt, Bm, Cm, h0, *, interpret: bool = False):
             jax.ShapeDtypeStruct((B, nh, hd, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

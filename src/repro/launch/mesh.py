@@ -16,10 +16,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     devices = jax.devices()
-    if len(devices) == n:
-        return jax.make_mesh(shape, axes)
     assert len(devices) >= n, (
         f"need {n} devices for mesh {shape}, have {len(devices)} — run under "
         f"launch/dryrun.py (it forces 512 host devices)"
     )
+    # Auto axes: the models place activations with sharding constraints,
+    # which explicit axes (jax.make_mesh's default) would refuse
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)

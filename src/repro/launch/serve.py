@@ -3,24 +3,40 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.1-8b --reduced \
         --requests 8 --chunk 32
 
-The physical KV page pool is sized from the serving hardware's real HBM
-budget (``--hw``, Table I archs): capacity minus resident weights, divided
-by one page's full-stack KV bytes — capped at the dense-equivalent layout
-(``max_batch * max_len`` tokens), which binds on reduced CPU configs where
-the HBM budget would dwarf what the slots can address. ``--num-kv-blocks``
-overrides the computed size explicitly.
+    # published widths, 16 of 32 layers, on one TPU v5e chip
+    PYTHONPATH=src python -m repro.launch.serve --arch llama3.1-8b --layers 16 \
+        --requests 8 --max-len 8192 --chunk 512 --max-batch 8 --max-new 32
+
+The model is served in bfloat16: weights (drawn directly in bf16 from
+``--seed``), activations and KV. ``--reduced`` shrinks widths and depth for
+a CPU run; ``--layers N`` cuts depth only, keeping the published widths.
+
+The physical KV page pool is sized from the device that serves: on a device
+that reports ``memory_stats()`` (a TPU), its ``bytes_limit`` less the
+resident weights and the step's temporaries; elsewhere from the simulator's
+HBM budget (``--hw``, Table I archs): capacity minus weights. Either way one
+page's full-stack KV bytes divide the budget, capped at the dense-equivalent
+layout (``max_batch * max_len`` tokens), which binds on reduced CPU configs
+where the budget would dwarf what the slots can address.
+``--num-kv-blocks`` overrides the computed size explicitly.
+
+JAX's persistent compilation cache lives where ``JAX_COMPILATION_CACHE_DIR``
+says, and otherwise in ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduce_config
-from repro.configs.reduced import dropless
+from repro.configs.reduced import cut_depth, dropless
 from repro.core.packed_step import supports_packed
 from repro.core.scheduler import SchedulerConfig
 from repro.memory.manager import hbm_kv_pool_blocks
@@ -50,10 +66,52 @@ def sized_kv_pool(cfg, hw_name: str, max_batch: int, max_len: int,
     return dense_equiv, "dense"
 
 
-def main():
+def device_kv_pool_blocks(cfg, free_bytes: int, n_rows: int, max_batch: int,
+                          max_len: int, kv_block: int):
+    """(pool_blocks, basis) that fit ``free_bytes`` of device memory left
+    after the weights, dense-capped. The packed step's temporaries come off
+    first: fp32 logits over every packed row (and one working copy), plus
+    the one layer's K/V pages the layer scan copies out of the pool per
+    page held. 5% stays free for the allocator's own slack."""
+    kv_per_token = cfg.kv_bytes_per_token_layer * cfg.n_attn_layers
+    page_bytes = kv_block * kv_per_token * (1 + 1 / max(cfg.n_attn_layers, 1))
+    logits = 2 * n_rows * cfg.vocab_size * 4
+    usable = int(free_bytes * 0.95) - logits
+    dense_equiv = max_batch * max_len // kv_block
+    budget = max(0, int(usable // page_bytes))
+    floor = max(1, max_len // kv_block)
+    if budget < floor:
+        raise ValueError(
+            f"device memory holds {budget} KV pages after the weights; one "
+            f"max_len context needs {floor}")
+    if budget < dense_equiv:
+        return budget, "device"
+    return dense_equiv, "dense"
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads it itself), or else at the
+    fixed ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    directory that moves never hits."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), serve the workload and
+    print the metrics line. Returns ``(engine, metrics)``; exits nonzero if
+    a request was left unfinished."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve only the first N layers at the published "
+                         "widths (depth-only cut; applied after --reduced)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and the workload")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--chunk", type=int, default=32)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -67,11 +125,12 @@ def main():
                     help="total KV token budget; exceeding it preempts decodes")
     ap.add_argument("--preemption", choices=["recompute", "swap"], default="recompute",
                     help="drop-and-re-prefill vs spill-to-host preemption")
-    ap.add_argument("--kv-block", type=int, default=1,
+    ap.add_argument("--kv-block", type=int, default=16,
                     help="paged KV block size in tokens")
     ap.add_argument("--hw", choices=sorted(HARDWARE), default="tpuv6e-like",
-                    help="serving hardware whose HBM budget sizes the KV "
-                         "page pool (capacity minus weights)")
+                    help="simulated serving hardware whose HBM budget sizes "
+                         "the KV page pool (capacity minus weights) when the "
+                         "device reports no memory_stats()")
     ap.add_argument("--num-kv-blocks", type=int, default=None,
                     help="explicit physical KV page pool size in blocks "
                          "(paged path; overrides the --hw HBM-budget sizing)."
@@ -126,19 +185,31 @@ def main():
                     help="rolling transfer-failure rate that trips degraded "
                          "mode (async prefetch off, admissions shed) until "
                          "the rate recovers")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
+    if args.layers is not None:
+        cfg = cut_depth(cfg, args.layers)
     cfg = dropless(cfg)  # serving uses dropless MoE dispatch
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model = build_model(cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
 
-    pool, pool_basis = args.num_kv_blocks, "flag"
+    pool, pool_basis, pool_src = args.num_kv_blocks, "flag", args.hw
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
     if pool is None and supports_packed(cfg) and args.attn_kernel != "dense":
-        pool, pool_basis = sized_kv_pool(cfg, args.hw, args.max_batch,
-                                         args.max_len, args.kv_block)
+        if stats and "bytes_limit" in stats:
+            jax.block_until_ready(params)
+            free = stats["bytes_limit"] - dev.memory_stats()["bytes_in_use"]
+            pool_src = dev.device_kind
+            pool, pool_basis = device_kv_pool_blocks(
+                cfg, free, args.max_batch + args.chunk, args.max_batch,
+                args.max_len, args.kv_block)
+        else:
+            pool, pool_basis = sized_kv_pool(cfg, args.hw, args.max_batch,
+                                             args.max_len, args.kv_block)
     fault_plan = None
     if args.fault_plan:
         from repro.robustness import FaultPlan
@@ -161,13 +232,15 @@ def main():
         max_transfer_retries=args.max_transfer_retries,
         request_timeout=args.request_timeout,
         degraded_threshold=args.degraded_threshold),
-        max_len=args.max_len, attn_kernel=args.attn_kernel, tracer=tracer)
-    rng = np.random.default_rng(0)
+        max_len=args.max_len, cache_dtype=jnp.bfloat16,
+        attn_kernel=args.attn_kernel, tracer=tracer)
+    rng = np.random.default_rng(args.seed)
     if args.shared_prefix > 0:
         for req in shared_prefix_requests(
                 args.requests, shared_len=args.shared_prefix,
                 unique_len=max(8, args.max_len // 8),
-                max_new_tokens=args.max_new, vocab_size=cfg.vocab_size):
+                max_new_tokens=args.max_new, vocab_size=cfg.vocab_size,
+                seed=args.seed):
             eng.submit(req)
     else:
         for rid in range(args.requests):
@@ -224,7 +297,7 @@ def main():
                else f"n/a(would_save={m['attn_padding_savings']:.2f})")
     alloc = eng.scheduler.mem.allocator
     pool_rep = (f"pool={alloc.peak_used_blocks}/{alloc.num_blocks}pages"
-                f"({pool_basis}:{args.hw}) "
+                f"({pool_basis}:{pool_src}) "
                 f"oob_stalls={int(m['out_of_block_stalls'])} "
                 f"wm_stalls={int(m['watermark_stalls'])} "
                 if ragged else "")
@@ -255,6 +328,7 @@ def main():
               f"{'...' if len(unfinished) > 16 else ''}"
               f"{' (interrupted)' if interrupted else ''}", file=sys.stderr)
         sys.exit(1)
+    return eng, m
 
 
 if __name__ == "__main__":

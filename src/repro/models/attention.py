@@ -37,16 +37,16 @@ NEG_INF = -2.0e38
 # ---------------------------------------------------------------------------
 
 
-def attn_init(rng, cfg: ModelConfig):
+def attn_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     if cfg.mla:
-        return _mla_init(rng, cfg)
+        return _mla_init(rng, cfg, dtype)
     ks = jax.random.split(rng, 6)
     d, hd = cfg.d_model, cfg.head_dim
     p = {
-        "wq": dense_init(ks[0], d, cfg.n_heads * hd, bias=cfg.qkv_bias),
-        "wk": dense_init(ks[1], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias),
-        "wv": dense_init(ks[2], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias),
-        "wo": dense_init(ks[3], cfg.n_heads * hd, d),
+        "wq": dense_init(ks[0], d, cfg.n_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
+        "wk": dense_init(ks[1], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
+        "wv": dense_init(ks[2], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
+        "wo": dense_init(ks[3], cfg.n_heads * hd, d, dtype=dtype),
     }
     if cfg.qk_norm:
         p["q_norm"] = rms_norm_init(hd)
@@ -54,20 +54,22 @@ def attn_init(rng, cfg: ModelConfig):
     return p
 
 
-def _mla_init(rng, cfg: ModelConfig):
+def _mla_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     ks = jax.random.split(rng, 8)
     d = cfg.d_model
     qk_head = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     return {
-        "q_down": dense_init(ks[0], d, cfg.q_lora_rank),
+        "q_down": dense_init(ks[0], d, cfg.q_lora_rank, dtype=dtype),
         "q_norm": rms_norm_init(cfg.q_lora_rank),
-        "q_up": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_head),
-        "kv_down": dense_init(ks[2], d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+        "q_up": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_head, dtype=dtype),
+        "kv_down": dense_init(ks[2], d, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                              dtype=dtype),
         "kv_norm": rms_norm_init(cfg.kv_lora_rank),
         "kv_up": dense_init(
-            ks[3], cfg.kv_lora_rank, cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            ks[3], cfg.kv_lora_rank, cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+            dtype=dtype,
         ),
-        "wo": dense_init(ks[4], cfg.n_heads * cfg.v_head_dim, d),
+        "wo": dense_init(ks[4], cfg.n_heads * cfg.v_head_dim, d, dtype=dtype),
     }
 
 
@@ -319,14 +321,14 @@ def _mla_apply(params, cfg: ModelConfig, x, positions, inv_freq, *, cache, cache
 # ---------------------------------------------------------------------------
 
 
-def cross_attn_init(rng, cfg: ModelConfig):
+def cross_attn_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     ks = jax.random.split(rng, 4)
     d, hd = cfg.d_model, cfg.head_dim
     return {
-        "wq": dense_init(ks[0], d, cfg.n_heads * hd, bias=cfg.qkv_bias),
-        "wk": dense_init(ks[1], d, cfg.n_kv_heads * hd),
-        "wv": dense_init(ks[2], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias),
-        "wo": dense_init(ks[3], cfg.n_heads * hd, d),
+        "wq": dense_init(ks[0], d, cfg.n_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
+        "wk": dense_init(ks[1], d, cfg.n_kv_heads * hd, dtype=dtype),
+        "wv": dense_init(ks[2], d, cfg.n_kv_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
+        "wo": dense_init(ks[3], cfg.n_heads * hd, d, dtype=dtype),
     }
 
 

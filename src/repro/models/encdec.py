@@ -31,41 +31,43 @@ def sinusoids(length: int, channels: int):
 # ---------------------------------------------------------------------------
 
 
-def _enc_layer_init(rng, cfg: ModelConfig):
+def _enc_layer_init(rng, cfg: ModelConfig, dtype):
     ks = jax.random.split(rng, 2)
     return {
         "norm1": layer_norm_init(cfg.d_model),
-        "attn": attention.attn_init(ks[0], cfg),
+        "attn": attention.attn_init(ks[0], cfg, dtype),
         "norm2": layer_norm_init(cfg.d_model),
-        "ffn": ffn_init(ks[1], cfg.d_model, cfg.d_ff, glu=cfg.glu, bias=True),
+        "ffn": ffn_init(ks[1], cfg.d_model, cfg.d_ff, glu=cfg.glu, bias=True, dtype=dtype),
     }
 
 
-def _dec_layer_init(rng, cfg: ModelConfig):
+def _dec_layer_init(rng, cfg: ModelConfig, dtype):
     ks = jax.random.split(rng, 3)
     return {
         "norm1": layer_norm_init(cfg.d_model),
-        "self_attn": attention.attn_init(ks[0], cfg),
+        "self_attn": attention.attn_init(ks[0], cfg, dtype),
         "norm_x": layer_norm_init(cfg.d_model),
-        "cross_attn": attention.cross_attn_init(ks[1], cfg),
+        "cross_attn": attention.cross_attn_init(ks[1], cfg, dtype),
         "norm2": layer_norm_init(cfg.d_model),
-        "ffn": ffn_init(ks[2], cfg.d_model, cfg.d_ff, glu=cfg.glu, bias=True),
+        "ffn": ffn_init(ks[2], cfg.d_model, cfg.d_ff, glu=cfg.glu, bias=True, dtype=dtype),
     }
 
 
-def encdec_init(rng, cfg: ModelConfig):
+def encdec_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     ks = jax.random.split(rng, cfg.n_enc_layers + cfg.n_layers + 2)
-    enc_layers = [_enc_layer_init(ks[i], cfg) for i in range(cfg.n_enc_layers)]
+    enc_layers = [_enc_layer_init(ks[i], cfg, dtype) for i in range(cfg.n_enc_layers)]
     dec_layers = [
-        _dec_layer_init(ks[cfg.n_enc_layers + i], cfg) for i in range(cfg.n_layers)
+        _dec_layer_init(ks[cfg.n_enc_layers + i], cfg, dtype) for i in range(cfg.n_layers)
     ]
     return {
         "enc": jax.tree.map(lambda *xs: jnp.stack(xs), *enc_layers),
         "dec": jax.tree.map(lambda *xs: jnp.stack(xs), *dec_layers),
         "enc_norm": layer_norm_init(cfg.d_model),
         "dec_norm": layer_norm_init(cfg.d_model),
-        "embed": truncated_normal(ks[-2], (cfg.vocab_size, cfg.d_model), std=0.02),
-        "pos_dec": truncated_normal(ks[-1], (cfg.max_seq_len, cfg.d_model), std=0.01),
+        "embed": truncated_normal(ks[-2], (cfg.vocab_size, cfg.d_model), std=0.02,
+                                  dtype=dtype),
+        "pos_dec": truncated_normal(ks[-1], (cfg.max_seq_len, cfg.d_model), std=0.01,
+                                    dtype=dtype),
     }
 
 

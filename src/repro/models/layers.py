@@ -2,8 +2,9 @@
 
 All modules are functional: ``init_*`` returns a params pytree (plain dicts),
 ``*_apply``-style functions take ``(params, x, ...)``. Compute dtype follows
-the input; params are stored in fp32 and cast at use (matching mixed-precision
-training practice).
+the input; weight matrices are drawn in the ``dtype`` the caller asks for
+(fp32 by default, matching mixed-precision training practice; bf16 for
+serving) and cast at use. Norm scales and biases stay fp32.
 """
 from __future__ import annotations
 
@@ -18,9 +19,9 @@ def truncated_normal(rng, shape, std=0.02, dtype=jnp.float32):
     return std * jax.random.truncated_normal(rng, -2.0, 2.0, shape, dtype)
 
 
-def dense_init(rng, d_in, d_out, *, std=None, bias=False):
+def dense_init(rng, d_in, d_out, *, std=None, bias=False, dtype=jnp.float32):
     std = std if std is not None else 1.0 / math.sqrt(d_in)
-    p = {"w": truncated_normal(rng, (d_in, d_out), std=std)}
+    p = {"w": truncated_normal(rng, (d_in, d_out), std=std, dtype=dtype)}
     if bias:
         p["b"] = jnp.zeros((d_out,), jnp.float32)
     return p
@@ -122,12 +123,12 @@ def apply_rope(x, positions, inv_freq):
 # ---------------------------------------------------------------------------
 
 
-def ffn_init(rng, d_model, d_ff, *, glu=True, bias=False):
+def ffn_init(rng, d_model, d_ff, *, glu=True, bias=False, dtype=jnp.float32):
     ks = jax.random.split(rng, 3)
-    p = {"up": dense_init(ks[0], d_model, d_ff, bias=bias),
-         "down": dense_init(ks[1], d_ff, d_model, bias=bias)}
+    p = {"up": dense_init(ks[0], d_model, d_ff, bias=bias, dtype=dtype),
+         "down": dense_init(ks[1], d_ff, d_model, bias=bias, dtype=dtype)}
     if glu:
-        p["gate"] = dense_init(ks[2], d_model, d_ff, bias=bias)
+        p["gate"] = dense_init(ks[2], d_model, d_ff, bias=bias, dtype=dtype)
     return p
 
 

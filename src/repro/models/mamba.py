@@ -53,7 +53,7 @@ def _m2_dims(cfg: ModelConfig):
     return d_in, nh, cfg.m_headdim, cfg.m_n_groups, cfg.m_d_state
 
 
-def mamba2_init(rng, cfg: ModelConfig):
+def mamba2_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     d_in, nh, hd, G, ds = _m2_dims(cfg)
     conv_ch = d_in + 2 * G * ds
     ks = jax.random.split(rng, 4)
@@ -61,14 +61,14 @@ def mamba2_init(rng, cfg: ModelConfig):
         jax.random.uniform(ks[2], (nh,)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001)
     )
     return {
-        "in_proj": dense_init(ks[0], cfg.d_model, 2 * d_in + 2 * G * ds + nh),
-        "conv_w": truncated_normal(ks[1], (cfg.m_conv, conv_ch), std=0.1),
+        "in_proj": dense_init(ks[0], cfg.d_model, 2 * d_in + 2 * G * ds + nh, dtype=dtype),
+        "conv_w": truncated_normal(ks[1], (cfg.m_conv, conv_ch), std=0.1, dtype=dtype),
         "conv_b": jnp.zeros((conv_ch,), jnp.float32),
         "A_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
         "D": jnp.ones((nh,), jnp.float32),
         "dt_bias": jnp.log(jnp.expm1(dt)),  # softplus^-1
         "norm_scale": jnp.ones((d_in,), jnp.float32),
-        "out_proj": dense_init(ks[3], d_in, cfg.d_model),
+        "out_proj": dense_init(ks[3], d_in, cfg.d_model, dtype=dtype),
     }
 
 
@@ -206,19 +206,19 @@ def _m1_dims(cfg: ModelConfig):
     return d_in, dt_rank, cfg.m_d_state_m1
 
 
-def mamba1_init(rng, cfg: ModelConfig):
+def mamba1_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     d_in, dt_rank, ds = _m1_dims(cfg)
     ks = jax.random.split(rng, 5)
     A = jnp.tile(jnp.arange(1, ds + 1, dtype=jnp.float32)[None, :], (d_in, 1))
     return {
-        "in_proj": dense_init(ks[0], cfg.d_model, 2 * d_in),
-        "conv_w": truncated_normal(ks[1], (cfg.m_conv, d_in), std=0.1),
+        "in_proj": dense_init(ks[0], cfg.d_model, 2 * d_in, dtype=dtype),
+        "conv_w": truncated_normal(ks[1], (cfg.m_conv, d_in), std=0.1, dtype=dtype),
         "conv_b": jnp.zeros((d_in,), jnp.float32),
-        "x_proj": dense_init(ks[2], d_in, dt_rank + 2 * ds),
-        "dt_proj": dense_init(ks[3], dt_rank, d_in, bias=True),
+        "x_proj": dense_init(ks[2], d_in, dt_rank + 2 * ds, dtype=dtype),
+        "dt_proj": dense_init(ks[3], dt_rank, d_in, bias=True, dtype=dtype),
         "A_log": jnp.log(A),
         "D": jnp.ones((d_in,), jnp.float32),
-        "out_proj": dense_init(ks[4], d_in, cfg.d_model),
+        "out_proj": dense_init(ks[4], d_in, cfg.d_model, dtype=dtype),
     }
 
 

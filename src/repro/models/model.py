@@ -54,8 +54,9 @@ def cross_entropy(logits, targets, mask=None):
 @dataclasses.dataclass
 class Model:
     cfg: ModelConfig
-    dtype: Any = jnp.float32
+    dtype: Any = jnp.float32  # activations
     remat: bool = False
+    param_dtype: Any = jnp.float32  # weight matrices drawn by init()
 
     def __post_init__(self):
         cfg = self.cfg
@@ -68,21 +69,26 @@ class Model:
 
     # ------------------------------------------------------------------ init
     def init(self, rng):
+        """Weight matrices are drawn directly in ``param_dtype`` (no fp32
+        draw then cast); norm scales and biases stay fp32."""
         cfg = self.cfg
+        dt = self.param_dtype
         if cfg.encdec:
-            return encdec.encdec_init(rng, cfg)
+            return encdec.encdec_init(rng, cfg, dt)
         k_embed, k_stack, k_head, k_pos = jax.random.split(rng, 4)
         params = {
-            "embed": truncated_normal(k_embed, (cfg.vocab_size, cfg.d_model), std=0.02),
-            "stack": stack.stack_init(k_stack, cfg),
+            "embed": truncated_normal(k_embed, (cfg.vocab_size, cfg.d_model), std=0.02,
+                                      dtype=dt),
+            "stack": stack.stack_init(k_stack, cfg, dt),
             "final_norm": rms_norm_init(cfg.d_model),
         }
         if not cfg.tie_embeddings:
             params["lm_head"] = truncated_normal(
-                k_head, (cfg.d_model, cfg.vocab_size), std=0.02
+                k_head, (cfg.d_model, cfg.vocab_size), std=0.02, dtype=dt
             )
         if cfg.learned_pos:
-            params["pos"] = truncated_normal(k_pos, (cfg.max_seq_len, cfg.d_model), std=0.01)
+            params["pos"] = truncated_normal(k_pos, (cfg.max_seq_len, cfg.d_model), std=0.01,
+                                             dtype=dt)
         return params
 
     # ------------------------------------------------------------- embeddings
@@ -213,5 +219,6 @@ class Model:
         return self._head(params, h)[:, 0], cache
 
 
-def build_model(cfg: ModelConfig, dtype=jnp.float32, remat: bool = False) -> Model:
-    return Model(cfg=cfg, dtype=dtype, remat=remat)
+def build_model(cfg: ModelConfig, dtype=jnp.float32, remat: bool = False,
+                param_dtype=jnp.float32) -> Model:
+    return Model(cfg=cfg, dtype=dtype, remat=remat, param_dtype=param_dtype)

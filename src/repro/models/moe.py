@@ -13,23 +13,25 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.ctx import shard_act, shard_map as _shard_map
+from repro.distributed.ctx import shard_act
 from repro.models.layers import dense_init, ffn, ffn_init
 
 CAPACITY_FACTOR = 1.25
 
 
-def moe_init(rng, cfg: ModelConfig):
+def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     ks = jax.random.split(rng, cfg.n_experts + 2)
     experts = [
-        ffn_init(ks[i], cfg.d_model, cfg.moe_d_ff, glu=cfg.glu) for i in range(cfg.n_experts)
+        ffn_init(ks[i], cfg.d_model, cfg.moe_d_ff, glu=cfg.glu, dtype=dtype)
+        for i in range(cfg.n_experts)
     ]
     p = {
-        "router": dense_init(ks[-2], cfg.d_model, cfg.n_experts, std=0.02),
+        "router": dense_init(ks[-2], cfg.d_model, cfg.n_experts, std=0.02, dtype=dtype),
         "experts": jax.tree.map(lambda *xs: jnp.stack(xs), *experts),
     }
     if cfg.n_shared_experts:
-        p["shared"] = ffn_init(ks[-1], cfg.d_model, cfg.shared_d_ff, glu=cfg.glu)
+        p["shared"] = ffn_init(ks[-1], cfg.d_model, cfg.shared_d_ff, glu=cfg.glu,
+                               dtype=dtype)
     return p
 
 
@@ -194,7 +196,7 @@ def _moe_apply_sharded(params, cfg: ModelConfig, x, mesh):
         aux = E * jnp.sum(me * ce_frac)
         return y.reshape(Bl, Tl, d), aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(specs_params, P(b_spec, None, None)),

@@ -28,15 +28,15 @@ from repro.models.layers import ffn, ffn_init, rms_norm, rms_norm_init
 # ---------------------------------------------------------------------------
 
 
-def layer_init(rng, cfg: ModelConfig, spec: LayerSpec):
+def layer_init(rng, cfg: ModelConfig, spec: LayerSpec, dtype=jnp.float32):
     ks = jax.random.split(rng, 4)
     p = {"norm1": rms_norm_init(cfg.d_model)}
     if spec.mixer == "attn":
-        p["mixer"] = attention.attn_init(ks[0], cfg)
+        p["mixer"] = attention.attn_init(ks[0], cfg, dtype)
     elif spec.mixer == "mamba2":
-        p["mixer"] = mamba.mamba2_init(ks[0], cfg)
+        p["mixer"] = mamba.mamba2_init(ks[0], cfg, dtype)
     elif spec.mixer == "mamba1":
-        p["mixer"] = mamba.mamba1_init(ks[0], cfg)
+        p["mixer"] = mamba.mamba1_init(ks[0], cfg, dtype)
     else:
         raise ValueError(spec.mixer)
     if cfg.post_norm:
@@ -44,9 +44,9 @@ def layer_init(rng, cfg: ModelConfig, spec: LayerSpec):
     if spec.ffn != "none":
         p["norm2"] = rms_norm_init(cfg.d_model)
         if spec.ffn == "dense":
-            p["ffn"] = ffn_init(ks[1], cfg.d_model, cfg.d_ff, glu=cfg.glu)
+            p["ffn"] = ffn_init(ks[1], cfg.d_model, cfg.d_ff, glu=cfg.glu, dtype=dtype)
         else:
-            p["ffn"] = moe.moe_init(ks[1], cfg)
+            p["ffn"] = moe.moe_init(ks[1], cfg, dtype)
         if cfg.post_norm:
             p["post_norm2"] = rms_norm_init(cfg.d_model)
     return p
@@ -108,19 +108,22 @@ def layer_apply(
 # ---------------------------------------------------------------------------
 
 
-def stack_init(rng, cfg: ModelConfig):
+def stack_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     n_pre = cfg.n_prefix_layers
     rngs = jax.random.split(rng, cfg.n_layers)
-    prefix = [layer_init(rngs[i], cfg, cfg.layer_specs[i]) for i in range(n_pre)]
-    periods = []
-    for c in range(cfg.n_periods):
-        base = n_pre + c * cfg.scan_period
-        period = {
-            str(i): layer_init(rngs[base + i], cfg, cfg.period_specs[i])
+    prefix = [layer_init(rngs[i], cfg, cfg.layer_specs[i], dtype) for i in range(n_pre)]
+
+    def period_init(keys):
+        return {
+            str(i): layer_init(keys[i], cfg, cfg.period_specs[i], dtype)
             for i in range(cfg.scan_period)
         }
-        periods.append(period)
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *periods) if periods else {}
+
+    # vmapped over periods: the draws equal per-layer draws bit for bit, the
+    # traced program holds one period (not n_layers), and the stacked
+    # params are built in place rather than stacked from per-layer copies
+    keys = rngs[n_pre:].reshape((cfg.n_periods, cfg.scan_period) + rngs.shape[1:])
+    stacked = jax.vmap(period_init)(keys) if cfg.n_periods else {}
     return {"prefix": prefix, "periods": stacked}
 
 

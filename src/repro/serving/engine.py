@@ -90,18 +90,23 @@ def _page_bucket(n: int) -> int:
 
 
 def _init_page_pool(model, n_pages: int, page_size: int, dtype):
-    """Allocate KV as a physical page pool: every cache leaf becomes
-    (n_pages, page_size, heads, head_dim) (period caches keep their leading
-    layer axis). Implemented as an engine-side adapter over
-    ``model.init_cache`` — one batch row of ``n_pages * page_size`` tokens
-    reshaped so each page is an independently addressable unit the block
-    tables can name in any order."""
-    flat = model.init_cache(1, n_pages * page_size, dtype)
+    """Allocate KV as a physical page pool: every GQA cache leaf becomes a
+    head-major (n_pages, heads, page_size, head_dim) array, every MLA latent
+    leaf (n_pages, page_size, width) (period caches keep their leading
+    layer axis). The shapes come from ``model.init_cache`` — one batch row
+    of ``n_pages * page_size`` tokens, traced, never allocated — regrouped
+    so each page is an independently addressable unit the block tables can
+    name in any order."""
+    flat = jax.eval_shape(lambda: model.init_cache(1, n_pages * page_size, dtype))
 
     def to_pool(key, leaf):
         ax = _batch_axis(key)  # batch (=1) at ax, token axis at ax+1
-        shape = leaf.shape
-        return leaf.reshape(shape[:ax] + (n_pages, page_size) + shape[ax + 2:])
+        lead, rest = leaf.shape[:ax], leaf.shape[ax + 2:]
+        if len(rest) == 2:  # (heads, head_dim): head-major pages
+            shape = lead + (n_pages, rest[0], page_size, rest[1])
+        else:  # latent width: no head axis
+            shape = lead + (n_pages, page_size) + rest
+        return jnp.zeros(shape, leaf.dtype)
 
     return {
         k: jax.tree.map(lambda l, k=k: to_pool(k, l), flat[k]) for k in flat
@@ -171,9 +176,15 @@ class Engine:
         # ragged paged attention is the packed default; it needs the page
         # size (= allocator block size) to tile max_len exactly
         self.page_size = sched_cfg.kv_block_size
+        on_tpu = jax.default_backend() == "tpu"
         if attn_kernel == "auto":
+            # the dense gather is a CPU debug path: on the chip a packed
+            # arch serves through the paged kernel or the check below
+            # refuses to start
             attn_kernel = (
-                "paged" if self.packed_mode and max_len % self.page_size == 0 else "dense"
+                "paged" if self.packed_mode and (
+                    on_tpu or max_len % self.page_size == 0)
+                else "dense"
             )
         if attn_kernel == "paged" and not (
             self.packed_mode and max_len % self.page_size == 0
@@ -221,7 +232,10 @@ class Engine:
             # fused page movers for swap traffic (the paged analogue of the
             # dense path's _gather_slot/_scatter_slot): one compiled call +
             # one host transfer per swapped request, ids padded to a pow2
-            # bucket of scratch pages so recompiles stay bounded
+            # bucket of scratch pages so recompiles stay bounded. The page
+            # axis leads every pool leaf (after the layer axis), so whole
+            # pages — all heads — move together. Writers donate the pool:
+            # it is updated in place, never copied.
             self._gather_pages = jax.jit(
                 lambda cache, ids: {
                     k: jax.tree.map(
@@ -240,7 +254,8 @@ class Engine:
                         cache[k], part[k],
                     )
                     for k in cache
-                }
+                },
+                donate_argnums=(0,),
             )
         else:
             # dense slot storage: +1 scratch row for padding tokens
@@ -252,7 +267,7 @@ class Engine:
 
         if self.packed_mode:
             if self.attn_kernel == "paged":
-                use_pallas = jax.default_backend() == "tpu"
+                use_pallas = on_tpu
                 page = self.page_size
                 # the unified mixed-batch attention path: ONE compiled call
                 # serves decode rows and packed prefill chunks alike, driven
@@ -267,6 +282,7 @@ class Engine:
                                         seg_slots=ss, q_block=qb),
                     ),
                     static_argnums=(9,),
+                    donate_argnums=(1,),
                 )
                 # mid-block prefix resume: batched copy-on-write page
                 # duplication (gather-then-scatter in ONE compiled call, so
@@ -281,7 +297,8 @@ class Engine:
                             cache[k],
                         )
                         for k in cache
-                    }
+                    },
+                    donate_argnums=(0,),
                 )
             else:
                 self._packed = jax.jit(

@@ -13,10 +13,10 @@ import pytest
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
 
-def _run(arch, shape, extra=(), timeout=420):
+def _run(out, arch, shape, extra=(), timeout=420):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    out = os.path.join(REPO, "benchmarks", "dryrun_results")
+    out = str(out)
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--out", out, "--tag", "citest", *extra]
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout, env=env)
@@ -24,14 +24,12 @@ def _run(arch, shape, extra=(), timeout=420):
     mesh = "pod2x16x16" if "--multi-pod" in extra else "pod16x16"
     path = os.path.join(out, f"{arch}__{shape}__{mesh}__citest.json")
     with open(path) as f:
-        rec = json.load(f)
-    os.remove(path)
-    return rec
+        return json.load(f)
 
 
 @pytest.mark.timeout(500)
-def test_dryrun_single_pod_decode():
-    rec = _run("qwen2-1.5b", "decode_32k")
+def test_dryrun_single_pod_decode(tmp_path):
+    rec = _run(tmp_path, "qwen2-1.5b", "decode_32k")
     assert rec["status"] == "ok"
     assert rec["n_devices"] == 256
     assert rec["cost"].get("flops", 0) > 0
@@ -40,16 +38,16 @@ def test_dryrun_single_pod_decode():
 
 
 @pytest.mark.timeout(500)
-def test_dryrun_multi_pod_train():
-    rec = _run("qwen2-1.5b", "train_4k", extra=("--multi-pod",))
+def test_dryrun_multi_pod_train(tmp_path):
+    rec = _run(tmp_path, "qwen2-1.5b", "train_4k", extra=("--multi-pod",))
     assert rec["status"] == "ok"
     assert rec["n_devices"] == 512
     # loop-trip scaling must amplify in-body collectives
     assert rec["collectives"]["total"] > rec["collectives"]["total_raw"]
 
 
-def test_dryrun_skip_cell():
-    rec = _run("qwen2-1.5b", "long_500k", timeout=120)
+def test_dryrun_skip_cell(tmp_path):
+    rec = _run(tmp_path, "qwen2-1.5b", "long_500k", timeout=120)
     assert rec["status"] == "skip"
     assert "full-attention" in rec["reason"]
 

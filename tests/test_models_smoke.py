@@ -122,3 +122,32 @@ def test_param_counts_full_configs():
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B not in [{lo/1e9},{hi/1e9}]B"
+
+
+def test_cut_depth_keeps_published_widths():
+    from repro.configs.reduced import cut_depth
+
+    full = get_config("llama3.1-8b")
+    cut = cut_depth(full, 16)
+    assert cut.n_layers == 16 and len(cut.layer_specs) == 16
+    for field in ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+                  "vocab_size"):
+        assert getattr(cut, field) == getattr(full, field), field
+    assert cut_depth(full, full.n_layers) is full
+    for bad in (0, full.n_layers + 1):
+        with pytest.raises(ValueError, match="cannot cut"):
+            cut_depth(full, bad)
+
+
+def test_bf16_init_draws_weights_in_bf16():
+    """Serving draws weight matrices directly in bf16 (no fp32 copy); norm
+    scales stay fp32."""
+    cfg = reduce_config(get_config("llama3.1-8b"))
+    params = jax.eval_shape(
+        build_model(cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16).init,
+        jax.random.PRNGKey(0))
+    layer = params["stack"]["periods"]["0"]
+    assert params["embed"].dtype == jnp.bfloat16
+    assert layer["mixer"]["wq"]["w"].dtype == jnp.bfloat16
+    assert layer["ffn"]["up"]["w"].dtype == jnp.bfloat16
+    assert layer["norm1"]["scale"].dtype == jnp.float32

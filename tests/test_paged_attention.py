@@ -29,11 +29,11 @@ def rand(rng, shape, dtype=jnp.float32):
 
 
 def dense_to_pool(k, page):
-    """(B, S, KV, d) slot cache -> (B*S/page, page, KV, d) page pool +
-    identity block tables (B, S/page)."""
+    """(B, S, KV, d) slot cache -> (B*S/page, KV, page, d) head-major page
+    pool + identity block tables (B, S/page)."""
     B, S, KV, d = k.shape
     pps = S // page
-    pool = k.reshape(B * pps, page, KV, d)
+    pool = k.reshape(B * pps, page, KV, d).transpose(0, 2, 1, 3)
     tables = (np.arange(B)[:, None] * pps + np.arange(pps)[None, :]).astype(np.int32)
     return pool, jnp.asarray(tables)
 
@@ -319,8 +319,8 @@ def _mixed_case(data, st):
         tables[s, :n_live] = page_ids[off:off + n_live]
         off += n_live
 
-    pool_k = rng.standard_normal((P, page, KV, d)).astype(np.float32)
-    pool_v = rng.standard_normal((P, page, KV, d)).astype(np.float32)
+    pool_k = rng.standard_normal((P, KV, page, d)).astype(np.float32)
+    pool_v = rng.standard_normal((P, KV, page, d)).astype(np.float32)
     pool_k[dead] = 999.0  # corrupted: any read would wreck the softmax
     pool_v[dead] = -999.0
 

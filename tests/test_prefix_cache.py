@@ -607,6 +607,51 @@ def test_sized_kv_pool_caps_and_floors():
     assert basis == "dense" and pool == 4 * 256 // 4
 
 
+def test_device_kv_pool_blocks_caps_and_floors():
+    from repro.configs.reduced import cut_depth
+    from repro.launch.serve import device_kv_pool_blocks
+
+    cfg = cut_depth(get_config("llama3.1-8b"), 16)
+    # one v5e after 16 layers of bf16 weights: the dense layout binds
+    pool, basis = device_kv_pool_blocks(cfg, 7 * 2**30, n_rows=520, max_batch=8,
+                                        max_len=8192, kv_block=16)
+    assert basis == "dense" and pool == 8 * 8192 // 16
+    # a tighter device: its budget binds, and still holds one context
+    pool, basis = device_kv_pool_blocks(cfg, 2 * 2**30, n_rows=520, max_batch=8,
+                                        max_len=8192, kv_block=16)
+    assert basis == "device" and 8192 // 16 <= pool < 8 * 8192 // 16
+    assert pool * 16 * 64 * 2**10 < 2 * 2**30  # 64 KiB of KV per token
+    with pytest.raises(ValueError, match="one max_len context"):
+        device_kv_pool_blocks(cfg, 2**28, n_rows=520, max_batch=8,
+                              max_len=8192, kv_block=16)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_location(env_dir, monkeypatch):
+    import os
+
+    import jax
+
+    from repro.launch import serve
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        serve.use_compile_cache()
+        got = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    if env_dir is None:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+    else:
+        assert got is None  # JAX reads the variable itself
+
+
 def test_shared_prefix_workload_shapes():
     reqs = shared_prefix_requests(n=5, shared_len=32, unique_len=8,
                                   max_new_tokens=4, seed=1)

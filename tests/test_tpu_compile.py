@@ -1,0 +1,114 @@
+"""Ahead-of-time compiles for a described TPU v5e: the served path's kernel
+and step at real widths, refused here exactly as the chip's compiler would
+refuse them (tiling, VMEM/SMEM, HBM fit). Nothing runs; no chip is needed.
+
+The topology is described inside a fixture — never at import, in a
+``skipif`` or in ``parametrize`` — so only the worker that runs this file
+loads the TPU compiler, and every worker collects the same tests.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attention import mixed_paged_attention
+
+V5E_HBM = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    # a described-device compile cannot be read back without a chip; keep
+    # the persistent cache out of it so nothing is written or warned about
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+# (KV, G, d, page, qb, S segments, nb table columns, window, softcap)
+CASES = {
+    "llama8b_decode": (8, 4, 128, 16, 1, 8, 512, None, None),
+    "llama8b_mixed": (8, 4, 128, 16, 32, 16, 512, None, None),
+    "llama8b_nb2048": (8, 4, 128, 16, 32, 40, 2048, None, None),
+    "gemma2b_window_softcap": (4, 2, 256, 16, 32, 16, 512, 4096, 50.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_mixed_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    KV, G, d, page, qb, S, nb, window, softcap = CASES[case]
+    N, P = 520, 4097
+    f = jax.jit(lambda q, k, v, cu, kl, bt: mixed_paged_attention(
+        q, k, v, cu, kl, bt, qb=qb, window=window, softcap=softcap))
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    compiled = f.lower(
+        _sds(one_chip, (N, KV, G, d), bf16), _sds(one_chip, (P, KV, page, d), bf16),
+        _sds(one_chip, (P, KV, page, d), bf16), _sds(one_chip, (S + 1,), i32),
+        _sds(one_chip, (S,), i32), _sds(one_chip, (S, nb), i32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_packed_step_fits_one_v5e(one_chip, no_compile_cache):
+    """The chip smoke's served step: Llama3.1-8B widths, 16 layers, bf16,
+    a 4096-page pool of 16 tokens. The pool is donated and updated in place
+    (aliased, not copied), and weights + pool + temporaries fit 16 GiB."""
+    from repro.configs import get_config
+    from repro.configs.reduced import cut_depth
+    from repro.core.packed_step import PagedView, packed_step
+    from repro.models import build_model
+    from repro.serving.engine import _init_page_pool
+
+    model = build_model(cut_depth(get_config("llama3.1-8b"), 16),
+                        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    place = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: _init_page_pool(model, 4097, 16, jnp.bfloat16)))
+    n_slots, chunk, sb, nb = 8, 512, 16, 512
+    i32 = lambda *s: _sds(one_chip, s, jnp.int32)
+    step = jax.jit(
+        lambda p, c, t, s, pos, bt, cq, kl, ss: packed_step(
+            model, p, c, t, s, pos,
+            paged=PagedView(bt, 16, use_kernel=True, cu_q_lens=cq, kv_lens=kl,
+                            seg_slots=ss, q_block=chunk)),
+        donate_argnums=(1,))
+    n = n_slots + chunk
+    compiled = step.lower(params, cache, i32(n), i32(n), i32(n),
+                          i32(n_slots + 1, nb), i32(sb + 1), i32(sb), i32(sb)).compile()
+    assert "mixed_paged_attention" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert peak < V5E_HBM, peak
