@@ -45,6 +45,15 @@ from repro.models.layers import apply_rope, dense, ffn, rms_norm
 from repro.models.model import Model
 
 
+# the step's named scopes (``jax.named_scope``): every operation of the
+# compiled step carries one in its metadata, so a profiler trace's device
+# time can be summed by part of the step (``Engine.step_op_scopes``).
+# ``kv_pool`` is the KV pool's movement: a layer's slice out of the scanned
+# pool, the scatter of the step's K/V and the write-back.
+STEP_SCOPES = ("embed", "qkv", "kv_pool", "attention", "o_proj", "mlp",
+               "lm_head")
+
+
 def supports_packed(cfg: ModelConfig) -> bool:
     return (not cfg.encdec) and all(s.mixer == "attn" for s in cfg.layer_specs)
 
@@ -112,16 +121,17 @@ def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache
                 paged: Optional["PagedView"] = None):
     N, _ = x.shape
     hd = cfg.head_dim
-    q = dense(p["wq"], x).reshape(N, 1, cfg.n_heads, hd)
-    k = dense(p["wk"], x).reshape(N, 1, cfg.n_kv_heads, hd)
-    v = dense(p["wv"], x).reshape(N, 1, cfg.n_kv_heads, hd)
-    if cfg.qk_norm:
-        q = rms_norm(p["q_norm"], q, cfg.norm_eps)
-        k = rms_norm(p["k_norm"], k, cfg.norm_eps)
-    pos2 = positions[:, None]  # (N,1)
-    q = apply_rope(q, pos2, inv_freq)[:, 0]  # (N,H,hd)
-    k = apply_rope(k, pos2, inv_freq)[:, 0]
-    v = v[:, 0]
+    with jax.named_scope("qkv"):
+        q = dense(p["wq"], x).reshape(N, 1, cfg.n_heads, hd)
+        k = dense(p["wk"], x).reshape(N, 1, cfg.n_kv_heads, hd)
+        v = dense(p["wv"], x).reshape(N, 1, cfg.n_kv_heads, hd)
+        if cfg.qk_norm:
+            q = rms_norm(p["q_norm"], q, cfg.norm_eps)
+            k = rms_norm(p["k_norm"], k, cfg.norm_eps)
+        pos2 = positions[:, None]  # (N,1)
+        q = apply_rope(q, pos2, inv_freq)[:, 0]  # (N,H,hd)
+        k = apply_rope(k, pos2, inv_freq)[:, 0]
+        v = v[:, 0]
 
     KV = cfg.n_kv_heads
     G = cfg.n_heads // KV
@@ -134,36 +144,42 @@ def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache
         # instead of O(N * S_max) or one prefix read per chunk token
         from repro.kernels.paged_attention import ragged_mixed_attention
 
-        ck = paged.scatter(cache["k"], slots, positions, k)
-        cv = paged.scatter(cache["v"], slots, positions, v)
-        o = ragged_mixed_attention(
-            q.reshape(N, KV, G, hd).astype(x.dtype),
-            ck, cv,
-            paged.cu_q_lens, paged.kv_lens, paged.seg_tables(),
-            qb=paged.q_block,
-            window=window, softcap=cfg.attn_logit_softcap,
-            use_kernel=paged.use_kernel, interpret=paged.interpret,
-        ).reshape(N, cfg.n_heads * hd)
-        return dense(p["wo"], o), {"k": ck, "v": cv}
+        with jax.named_scope("kv_pool"):
+            ck = paged.scatter(cache["k"], slots, positions, k)
+            cv = paged.scatter(cache["v"], slots, positions, v)
+        with jax.named_scope("attention"):
+            o = ragged_mixed_attention(
+                q.reshape(N, KV, G, hd).astype(x.dtype),
+                ck, cv,
+                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(),
+                qb=paged.q_block,
+                window=window, softcap=cfg.attn_logit_softcap,
+                use_kernel=paged.use_kernel, interpret=paged.interpret,
+            ).reshape(N, cfg.n_heads * hd)
+        with jax.named_scope("o_proj"):
+            return dense(p["wo"], o), {"k": ck, "v": cv}
 
-    ck = cache["k"].at[slots, positions].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[slots, positions].set(v.astype(cache["v"].dtype))
+    with jax.named_scope("kv_pool"):
+        ck = cache["k"].at[slots, positions].set(k.astype(cache["k"].dtype))
+        cv = cache["v"].at[slots, positions].set(v.astype(cache["v"].dtype))
     new_cache = {"k": ck, "v": cv}
 
-    S = ck.shape[1]
-    kc = ck[slots].astype(x.dtype)  # (N,S,KV,hd)
-    vc = cv[slots].astype(x.dtype)
-    qg = q.reshape(N, KV, G, hd)
-    s = jnp.einsum("nkgh,nskh->nkgs", qg, kc).astype(jnp.float32) / hd**0.5
-    s = softcap(s, cfg.attn_logit_softcap)
-    k_pos = jnp.arange(S)[None, :]
-    ok = k_pos <= positions[:, None]
-    if window is not None:
-        ok &= k_pos > positions[:, None] - window
-    s = jnp.where(ok[:, None, None, :], s, NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    o = jnp.einsum("nkgs,nskh->nkgh", probs, vc).reshape(N, cfg.n_heads * hd)
-    return dense(p["wo"], o), new_cache
+    with jax.named_scope("attention"):
+        S = ck.shape[1]
+        kc = ck[slots].astype(x.dtype)  # (N,S,KV,hd)
+        vc = cv[slots].astype(x.dtype)
+        qg = q.reshape(N, KV, G, hd)
+        s = jnp.einsum("nkgh,nskh->nkgs", qg, kc).astype(jnp.float32) / hd**0.5
+        s = softcap(s, cfg.attn_logit_softcap)
+        k_pos = jnp.arange(S)[None, :]
+        ok = k_pos <= positions[:, None]
+        if window is not None:
+            ok &= k_pos > positions[:, None] - window
+        s = jnp.where(ok[:, None, None, :], s, NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        o = jnp.einsum("nkgs,nskh->nkgh", probs, vc).reshape(N, cfg.n_heads * hd)
+    with jax.named_scope("o_proj"):
+        return dense(p["wo"], o), new_cache
 
 
 def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
@@ -174,66 +190,75 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
     H = cfg.n_heads
     nope, rope, vh = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     scale = 1.0 / ((nope + rope) ** 0.5)
-    q_nope, q_rope, ckv, krope = _mla_qkv_rope(p, cfg, x[:, None, :], positions[:, None], inv_freq)
-    q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]  # (N,H,*)
-    ckv, krope = ckv[:, 0], krope[:, 0]  # (N,L), (N,rope)
+    with jax.named_scope("qkv"):
+        q_nope, q_rope, ckv, krope = _mla_qkv_rope(
+            p, cfg, x[:, None, :], positions[:, None], inv_freq)
+        q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]  # (N,H,*)
+        ckv, krope = ckv[:, 0], krope[:, 0]  # (N,L), (N,rope)
 
-    if paged is not None:
-        cc = paged.scatter(cache["ckv"], slots, positions, ckv)
-        cr = paged.scatter(cache["krope"], slots, positions, krope)
-    else:
-        cc = cache["ckv"].at[slots, positions].set(ckv.astype(cache["ckv"].dtype))
-        cr = cache["krope"].at[slots, positions].set(krope.astype(cache["krope"].dtype))
+    with jax.named_scope("kv_pool"):
+        if paged is not None:
+            cc = paged.scatter(cache["ckv"], slots, positions, ckv)
+            cr = paged.scatter(cache["krope"], slots, positions, krope)
+        else:
+            cc = cache["ckv"].at[slots, positions].set(ckv.astype(cache["ckv"].dtype))
+            cr = cache["krope"].at[slots, positions].set(krope.astype(cache["krope"].dtype))
     new_cache = {"ckv": cc, "krope": cr}
 
-    w_up = p["kv_up"]["w"].reshape(cfg.kv_lora_rank, H, nope + vh)
-    w_uk, w_uv = w_up[..., :nope], w_up[..., nope:]
-    q_eff = jnp.einsum("nhp,lhp->nhl", q_nope, w_uk.astype(x.dtype))
-    if paged is not None:
-        # ragged block-table gather of the latent page pool, bounded to the
-        # live context (nb pages) — the MLA analogue of the paged GQA path
-        tabs = paged.row_tables(slots)  # (N, nb)
-        nb = tabs.shape[1]
-        Sr = nb * paged.page_size
-        c = cc[tabs].reshape(N, Sr, cfg.kv_lora_rank).astype(x.dtype)
-        kr = cr[tabs].reshape(N, Sr, rope).astype(x.dtype)
-        k_pos = jnp.arange(Sr)[None, :]
-    else:
-        Sr = cc.shape[1]
-        c = cc[slots].astype(x.dtype)  # (N,S,L)
-        kr = cr[slots].astype(x.dtype)  # (N,S,rope)
-        k_pos = jnp.arange(Sr)[None, :]
-    s = jnp.einsum("nhl,nsl->nhs", q_eff, c) + jnp.einsum("nhr,nsr->nhs", q_rope, kr)
-    s = s.astype(jnp.float32) * scale
-    ok = k_pos <= positions[:, None]
-    s = jnp.where(ok[:, None, :], s, NEG_INF)
-    probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("nhs,nsl->nhl", probs, c)
-    o = jnp.einsum("nhl,lhv->nhv", o_lat, w_uv.astype(x.dtype)).reshape(N, H * vh)
-    return dense(p["wo"], o), new_cache
+    with jax.named_scope("attention"):
+        w_up = p["kv_up"]["w"].reshape(cfg.kv_lora_rank, H, nope + vh)
+        w_uk, w_uv = w_up[..., :nope], w_up[..., nope:]
+        q_eff = jnp.einsum("nhp,lhp->nhl", q_nope, w_uk.astype(x.dtype))
+        if paged is not None:
+            # ragged block-table gather of the latent page pool, bounded to
+            # the live context (nb pages) — the MLA analogue of the paged
+            # GQA path
+            tabs = paged.row_tables(slots)  # (N, nb)
+            nb = tabs.shape[1]
+            Sr = nb * paged.page_size
+            c = cc[tabs].reshape(N, Sr, cfg.kv_lora_rank).astype(x.dtype)
+            kr = cr[tabs].reshape(N, Sr, rope).astype(x.dtype)
+            k_pos = jnp.arange(Sr)[None, :]
+        else:
+            Sr = cc.shape[1]
+            c = cc[slots].astype(x.dtype)  # (N,S,L)
+            kr = cr[slots].astype(x.dtype)  # (N,S,rope)
+            k_pos = jnp.arange(Sr)[None, :]
+        s = jnp.einsum("nhl,nsl->nhs", q_eff, c) + jnp.einsum("nhr,nsr->nhs", q_rope, kr)
+        s = s.astype(jnp.float32) * scale
+        ok = k_pos <= positions[:, None]
+        s = jnp.where(ok[:, None, :], s, NEG_INF)
+        probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+        o_lat = jnp.einsum("nhs,nsl->nhl", probs, c)
+        o = jnp.einsum("nhl,lhv->nhv", o_lat, w_uv.astype(x.dtype)).reshape(N, H * vh)
+    with jax.named_scope("o_proj"):
+        return dense(p["wo"], o), new_cache
 
 
 def _packed_layer(p, cfg, spec, x, slots, positions, cache, inv_freq, paged=None):
-    hn = rms_norm(p["norm1"], x, cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        hn = rms_norm(p["norm1"], x, cfg.norm_eps)
     if cfg.mla:
         y, new_cache = _packed_mla(p["mixer"], cfg, hn, slots, positions, cache, inv_freq,
                                    paged=paged)
     else:
         y, new_cache = _packed_gqa(p["mixer"], cfg, spec, hn, slots, positions, cache, inv_freq,
                                    paged=paged)
-    if cfg.post_norm:
-        y = rms_norm(p["post_norm1"], y, cfg.norm_eps)
-    x = x + y
-    if spec.ffn != "none":
-        hn = rms_norm(p["norm2"], x, cfg.norm_eps)
-        if spec.ffn == "dense":
-            y = ffn(p["ffn"], hn, cfg.act, cfg.glu)
-        else:
-            y, _ = moe.moe_apply(p["ffn"], cfg, hn[None])  # (1,N,d)
-            y = y[0]
+    with jax.named_scope("o_proj"):
         if cfg.post_norm:
-            y = rms_norm(p["post_norm2"], y, cfg.norm_eps)
+            y = rms_norm(p["post_norm1"], y, cfg.norm_eps)
         x = x + y
+    if spec.ffn != "none":
+        with jax.named_scope("mlp"):
+            hn = rms_norm(p["norm2"], x, cfg.norm_eps)
+            if spec.ffn == "dense":
+                y = ffn(p["ffn"], hn, cfg.act, cfg.glu)
+            else:
+                y, _ = moe.moe_apply(p["ffn"], cfg, hn[None])  # (1,N,d)
+                y = y[0]
+            if cfg.post_norm:
+                y = rms_norm(p["post_norm2"], y, cfg.norm_eps)
+            x = x + y
     return x, new_cache
 
 
@@ -252,9 +277,10 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
     """
     cfg = model.cfg
     assert supports_packed(cfg), cfg.name
-    x = jnp.take(params["embed"], tokens, axis=0).astype(model.dtype)
-    if cfg.scale_embeddings:
-        x = x * jnp.asarray(cfg.d_model**0.5, model.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(model.dtype)
+        if cfg.scale_embeddings:
+            x = x * jnp.asarray(cfg.d_model**0.5, model.dtype)
 
     new_prefix = []
     for i in range(cfg.n_prefix_layers):
@@ -270,7 +296,8 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
     def body(carry, xs):
         x, periods = carry
         p_period, layer = xs
-        cache_period = jax.tree.map(lambda c: c[layer], periods)
+        with jax.named_scope("kv_pool"):
+            cache_period = jax.tree.map(lambda c: c[layer], periods)
         new_cache = {}
         for i in range(cfg.scan_period):
             x, nc = _packed_layer(
@@ -278,20 +305,24 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
                 cache_period[str(i)], model.inv_freq, paged=paged,
             )
             new_cache[str(i)] = nc
-        periods = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
-            periods, new_cache)
+        with jax.named_scope("kv_pool"):
+            periods = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
+                periods, new_cache)
         return (x, periods), None
 
     if cfg.n_periods:
+        with jax.named_scope("kv_pool"):  # the index only selects pool layers
+            layers = jnp.arange(cfg.n_periods)
         (x, new_periods), _ = jax.lax.scan(
-            body, (x, cache["periods"]),
-            (params["stack"]["periods"], jnp.arange(cfg.n_periods)),
+            body, (x, cache["periods"]), (params["stack"]["periods"], layers),
         )
     else:
         new_periods = cache["periods"]
 
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap((x @ w.astype(x.dtype)).astype(jnp.float32), cfg.final_logit_softcap)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(params["final_norm"], x, cfg.norm_eps)
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = softcap((x @ w.astype(x.dtype)).astype(jnp.float32),
+                         cfg.final_logit_softcap)
     return logits, {"prefix": new_prefix, "periods": new_periods}

@@ -114,6 +114,11 @@ def to_chrome(rec: TraceRecorder) -> Dict[str, object]:
         "otherData": {
             "backend": rec.backend,
             "clock": "simulated" if rec.manual_clock else "wall",
+            # ts 0 on both clocks, read together at recorder creation: the
+            # perf_counter seconds and the wall-clock ns since the epoch, for
+            # lining the trace up with a JAX profiler trace
+            "origin_perf_counter_s": rec.origin[0],
+            "origin_unix_ns": rec.origin[1],
             "generator": "repro.obs",
         },
     }

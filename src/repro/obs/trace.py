@@ -40,13 +40,27 @@ does no per-event work at all.
 Clocks: the engine uses a monotonic wall clock (``time.perf_counter``
 relative to recorder creation); the simulator drives a *manual* clock via
 ``set_time`` so every event stamps simulated seconds.  ``now()`` hides the
-difference from the Scheduler.
+difference from the Scheduler.  A recorder keeps its origin twice, as
+``perf_counter`` and as ``time.time_ns()`` taken together, so an exported
+engine trace can be shifted onto a JAX profiler trace's clock.
+
+Host spans on the profiler clock
+--------------------------------
+``span`` times one phase of host work three ways at once: a
+``jax.profiler.TraceAnnotation`` (so a profiler trace shows the phase beside
+the device's operations), cumulative seconds and calls in the caller's
+``(seconds, calls)`` dicts (cheap operator counters, always on), and a
+recorder span when a recorder is enabled.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 # lanes (exported so the checker/docs agree on names)
 LANE_STEP = "step"
@@ -59,11 +73,32 @@ LANE_HBM_FILL = "hbm_fill"
 LANE_PREFETCH_STAGE = "prefetch_stage"
 LANE_QUEUE = "prefetch_queue"
 LANE_ATTRIBUTION = "attribution"
+LANE_HOST = "host"
 PIPELINE_LANES = (
     LANE_STEP, LANE_SCHED, LANE_COMPUTE, LANE_STALL_SYNC,
     LANE_STALL_PREFETCH, LANE_HOST_LINK, LANE_HBM_FILL,
-    LANE_PREFETCH_STAGE, LANE_QUEUE, LANE_ATTRIBUTION,
+    LANE_PREFETCH_STAGE, LANE_QUEUE, LANE_ATTRIBUTION, LANE_HOST,
 )
+
+# an HLO instruction line: its name and the op_name of its metadata
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def hlo_op_scopes(hlo_text: str, scopes) -> Dict[str, str]:
+    """{instruction name: scope} from a compiled module's HLO text: each
+    instruction whose ``metadata={op_name=...}`` path names one of
+    ``scopes`` (``jax.named_scope``) maps to the first it names. The names
+    are those of the ``XLA Ops`` events in a profiler trace."""
+    known = set(scopes)
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            scope = next((p for p in m.group(2).split("/") if p in known), None)
+            if scope is not None:
+                out[m.group(1)] = scope
+    return out
+
 
 # request lifecycle transitions -> the state span they open (None = closed).
 # "first_token" and "prefill_done" both enter decode: the former fires only
@@ -136,6 +171,32 @@ class NoopTracer:
 
 NOOP = NoopTracer()
 
+SpanTotals = Tuple[Dict[str, float], Dict[str, int]]  # (seconds, calls) by name
+
+
+@contextlib.contextmanager
+def span(name: str, lane: str, tracer, acc: SpanTotals,
+         step_num: Optional[int] = None, **args) -> Iterator[dict]:
+    """Time the enclosed host work as span ``name``: a profiler annotation
+    (``StepTraceAnnotation`` when ``step_num`` is given, so the profiler
+    marks a step), the perf_counter duration and one call added to ``acc``,
+    and, when ``tracer`` records, a span on ``lane`` carrying ``args``.
+    Yields ``args``, which the caller may extend before the span closes."""
+    ann = (jax.profiler.TraceAnnotation(name) if step_num is None else
+           jax.profiler.StepTraceAnnotation(name, step_num=step_num))
+    ts = tracer.now() if tracer.enabled else 0.0
+    t = time.perf_counter()
+    try:
+        with ann:
+            yield args
+    finally:
+        dt = time.perf_counter() - t
+        seconds, calls = acc
+        seconds[name] = seconds.get(name, 0.0) + dt
+        calls[name] = calls.get(name, 0) + 1
+        if tracer.enabled:
+            tracer.span(lane, name, ts, dt, **args)
+
 
 class TraceRecorder:
     """Collects typed events; export with ``repro.obs.perfetto``."""
@@ -148,6 +209,9 @@ class TraceRecorder:
         self.events: List[TraceEvent] = []
         self._t = 0.0
         self._t0 = time.perf_counter()
+        # the origin on both clocks, read together: (perf_counter seconds,
+        # wall-clock ns since the epoch)
+        self.origin = (self._t0, time.time_ns())
         # rid -> (open state name, open ts) for lifecycle span derivation
         self._open_state: Dict[int, Tuple[str, float]] = {}
 

@@ -53,7 +53,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.packed_step import PagedView, packed_step, supports_packed
+from repro.core.packed_step import (
+    STEP_SCOPES,
+    PagedView,
+    packed_step,
+    supports_packed,
+)
 from repro.core.scheduler import Scheduler, SchedulerConfig, StepPlan
 from repro.memory.prefetch_queue import ADOPT, SWAP_IN
 from repro.models.model import Model
@@ -64,11 +69,14 @@ from repro.obs.attribution import (
 )
 from repro.obs.trace import (
     LANE_COMPUTE,
+    LANE_HOST,
     LANE_HOST_LINK,
     LANE_PREFETCH_STAGE,
     LANE_SCHED,
     LANE_STEP,
     NOOP,
+    hlo_op_scopes,
+    span,
 )
 from repro.serving.request import Request, State
 
@@ -145,11 +153,9 @@ class Engine:
         cache_dtype=jnp.float32,
         eos_id: Optional[int] = None,
         attn_kernel: str = "auto",
-        tracer=None,  # a repro.obs TraceRecorder (wall clock) — records step
-        # phase spans (schedule / swap / compute dispatch / prefetch stage),
-        # request lifecycles, and the transfer ledger. Phase durations are
-        # host dispatch times: JAX dispatch is asynchronous, so "compute"
-        # measures enqueue latency, not device occupancy.
+        tracer=None,  # a repro.obs TraceRecorder (wall clock) — records the
+        # step's phase spans (see ``step``), request lifecycles, and the
+        # transfer ledger
     ):
         if attn_kernel not in ATTN_KERNELS:
             raise ValueError(f"unknown attn_kernel {attn_kernel!r}; want one of {ATTN_KERNELS}")
@@ -162,6 +168,10 @@ class Engine:
         self.n_slots = sched_cfg.max_decode_batch
         self.bucket = self.n_slots + sched_cfg.chunk_size
         self.steps_run = 0
+        # host seconds and calls per step phase, cumulative (``span``)
+        self.span_s: Dict[str, float] = {}
+        self.span_n: Dict[str, int] = {}
+        self._spans = (self.span_s, self.span_n)
         self.prefetch_log: List[float] = []
         # swap-style preemption: host-DRAM copies of spilled KV (whole pages
         # in paged mode, slot rows in dense mode), keyed by rid — the "host
@@ -236,17 +246,17 @@ class Engine:
             # axis leads every pool leaf (after the layer axis), so whole
             # pages — all heads — move together. Writers donate the pool:
             # it is updated in place, never copied.
-            self._gather_pages = jax.jit(
-                lambda cache, ids: {
+            def gather_pages(cache, ids):
+                return {
                     k: jax.tree.map(
                         lambda l, a=_batch_axis(k): jnp.take(l, ids, axis=a),
                         cache[k],
                     )
                     for k in cache
                 }
-            )
-            self._scatter_pages = jax.jit(
-                lambda cache, part, ids: {
+
+            def scatter_pages(cache, part, ids):
+                return {
                     k: jax.tree.map(
                         lambda l, h, a=_batch_axis(k): l.at[
                             (slice(None),) * a + (ids,)
@@ -254,9 +264,10 @@ class Engine:
                         cache[k], part[k],
                     )
                     for k in cache
-                },
-                donate_argnums=(0,),
-            )
+                }
+
+            self._gather_pages = jax.jit(gather_pages)
+            self._scatter_pages = jax.jit(scatter_pages, donate_argnums=(0,))
         else:
             # dense slot storage: +1 scratch row for padding tokens
             self.cache = model.init_cache(self.n_slots + 1, max_len, cache_dtype)
@@ -274,22 +285,23 @@ class Engine:
                 # by the plan's segment layout (cu_q_lens / kv_lens /
                 # seg_slots). ``qb`` — the pow2 q-block rows bucket — is
                 # static so the kernel tiles each segment's queries exactly.
-                self._packed = jax.jit(
-                    lambda p, c, t, s, pos, bt, cq, kl, ss, qb: packed_step(
+                def paged_step(p, c, t, s, pos, bt, cq, kl, ss, qb):
+                    return packed_step(
                         model, p, c, t, s, pos,
                         paged=PagedView(bt, page, use_kernel=use_pallas,
                                         cu_q_lens=cq, kv_lens=kl,
                                         seg_slots=ss, q_block=qb),
-                    ),
-                    static_argnums=(9,),
-                    donate_argnums=(1,),
-                )
+                    )
+
+                self._packed = jax.jit(paged_step, static_argnums=(9,),
+                                       donate_argnums=(1,))
+
                 # mid-block prefix resume: batched copy-on-write page
                 # duplication (gather-then-scatter in ONE compiled call, so
                 # every source is read from the pre-copy array before any
                 # destination is written)
-                self._copy_pages = jax.jit(
-                    lambda cache, src, dst: {
+                def copy_pages(cache, src, dst):
+                    return {
                         k: jax.tree.map(
                             lambda l, a=_batch_axis(k): l.at[
                                 (slice(None),) * a + (dst,)
@@ -297,13 +309,14 @@ class Engine:
                             cache[k],
                         )
                         for k in cache
-                    },
-                    donate_argnums=(0,),
-                )
+                    }
+
+                self._copy_pages = jax.jit(copy_pages, donate_argnums=(0,))
             else:
-                self._packed = jax.jit(
-                    lambda p, c, t, s, pos: packed_step(model, p, c, t, s, pos)
-                )
+                def dense_step(p, c, t, s, pos):
+                    return packed_step(model, p, c, t, s, pos)
+
+                self._packed = jax.jit(dense_step)
         else:
             # per-arch decode/prefill entry points exist ONLY for the
             # two-call (SSM/hybrid/encdec) path — attention-family archs run
@@ -313,8 +326,8 @@ class Engine:
             # jitted slot zero-reset for two-call re-prefills (slot reuse):
             # the zeros tree is built inside the compiled call, not rebuilt
             # per use
-            self._reset_slot = jax.jit(
-                lambda cache, slot: {
+            def reset_slot(cache, slot):
+                return {
                     k: _put_slot(
                         cache[k],
                         jax.tree.map(
@@ -329,22 +342,22 @@ class Engine:
                     )
                     for k in cache
                 }
-            )
+
+            self._reset_slot = jax.jit(reset_slot)
         if self.attn_kernel != "paged":
             # fused single-call slot movers for dense swap traffic: one
             # compiled gather/scatter over the whole cache tree per swapped
             # request (the paged path moves pages via _gather/_scatter_pages)
-            self._gather_slot = jax.jit(
-                lambda cache, slot: {
-                    k: _take_slot(cache[k], slot, _batch_axis(k)) for k in cache
-                }
-            )
-            self._scatter_slot = jax.jit(
-                lambda cache, part, slot: {
-                    k: _put_slot(cache[k], part[k], slot, _batch_axis(k))
-                    for k in cache
-                }
-            )
+            def gather_slot(cache, slot):
+                return {k: _take_slot(cache[k], slot, _batch_axis(k))
+                        for k in cache}
+
+            def scatter_slot(cache, part, slot):
+                return {k: _put_slot(cache[k], part[k], slot, _batch_axis(k))
+                        for k in cache}
+
+            self._gather_slot = jax.jit(gather_slot)
+            self._scatter_slot = jax.jit(scatter_slot)
 
     # ------------------------------------------------------------------ API
     def submit(self, req: Request) -> None:
@@ -378,6 +391,11 @@ class Engine:
         host-tier occupancy, and (paged mode) pool capacity/peak pressure."""
         reg.counter("engine_steps", "steps", "engine steps executed").inc(
             self.steps_run)
+        for name, secs in self.span_s.items():
+            reg.counter(f"span_s.{name}", "s",
+                        f"host seconds in {name}").inc(secs)
+            reg.counter(f"span_n.{name}", "calls",
+                        f"calls of {name}").inc(self.span_n[name])
         reg.gauge("engine_swap_store_entries", "requests",
                   "host-tier KV copies currently held").set(
                       float(len(self.swap_store)))
@@ -391,6 +409,26 @@ class Engine:
         if self.scheduler.injector.enabled:
             self.scheduler.injector.register_metrics(reg)
         self.scheduler.ledger.register_metrics(reg)
+
+    def step_op_scopes(self, nb: int, sb: int, qb: int) -> Dict[str, str]:
+        """{HLO instruction name: named scope} of the paged packed step
+        compiled for ``nb`` table columns, ``sb`` segments and q-block
+        ``qb`` (``_run_packed``'s buckets), so the device operations of a
+        profiler trace can be summed by ``STEP_SCOPES``. Lowers and compiles
+        the step from shapes alone (a compile-cache hit where the step ran);
+        nothing runs."""
+        def like(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        rows = i32(self.bucket)
+        lowered = self._packed.lower(
+            jax.tree.map(like, self.params), jax.tree.map(like, self.cache),
+            rows, rows, rows, i32(self.n_slots + 1, nb), i32(sb + 1), i32(sb),
+            i32(sb), qb)
+        return hlo_op_scopes(lowered.compile().as_text(), STEP_SCOPES)
 
     def attribution_aggregates(self) -> Dict[str, float]:
         """The engine's independently accumulated byte counters, keyed by
@@ -413,53 +451,60 @@ class Engine:
 
     # ----------------------------------------------------------------- steps
     def step(self, now: float = 0.0) -> Optional[StepPlan]:
-        tr = self.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        plan = self.scheduler.next_step(now)
-        self._purge_released()  # even a None plan may have cancelled requests
-        if plan is None:
-            return None
-        if plan.prefetch is not None:
-            self.prefetch_log.append(plan.prefetch.coverage)
-        t1 = tr.now() if tr.enabled else 0.0
-        # copy-on-write page duplication for mid-block prefix resumes MUST
-        # run before any other device write this step: sources are cached
-        # pages whose ids were valid at plan time, and neither swap traffic
-        # nor the compute scatter has touched the pool yet
-        self._apply_prefix_copies(plan)
-        self._apply_swaps(plan)
-        self._verify_landed(plan)
-        t2 = tr.now() if tr.enabled else 0.0
-        if self.packed_mode:
-            self._run_packed(plan)
-        else:
-            self._run_two_call(plan)
-        t3 = tr.now() if tr.enabled else 0.0
-        # stage next step's predicted transfers NOW: the compute above is
-        # dispatched but (on an async backend) still in flight, so these
-        # host->device copies ride under it
-        self._issue_prefetch(plan)
-        self.scheduler.complete_step(plan, now)
-        # emit the step's attribution instant at the same point in the
-        # event stream as the sim (right after complete_step), so the two
-        # backends' sched sequences stay position-aligned for --compare
-        self.scheduler.ledger.record_step(tr, plan.step)
-        if tr.enabled:
-            t4 = tr.now()
-            step = self.steps_run
-            tr.span(LANE_STEP, f"step {step}", t0, t4 - t0, step=step,
-                    tokens=plan.total_tokens, decodes=len(plan.decode_rids),
-                    prefill_tokens=plan.total_prefill_tokens)
-            tr.span(LANE_SCHED, "next_step", t0, t1 - t0, step=step)
-            if plan.swapped_out or plan.swapped_in:
-                tr.span(LANE_HOST_LINK, "apply_swaps", t1, t2 - t1,
-                        step=step, swap_out=len(plan.swapped_out),
-                        swap_in=len(plan.swapped_in))
-            tr.span(LANE_COMPUTE, "dispatch", t2, t3 - t2, step=step,
-                    tokens=plan.total_tokens)
-            tr.span(LANE_PREFETCH_STAGE, "stage+complete", t3, t4 - t3,
-                    step=step, issued=len(plan.issued))
-        self.steps_run += 1
+        """Run one scheduled step. Its host work is tiled by spans (``span``:
+        profiler annotations, ``span_s``/``span_n``, recorder spans), all
+        inside ``engine.step``: ``sched.next_step``,
+        ``engine.prefix_copies``, ``engine.swaps``, then the packed phases
+        (``engine.pack``, ``engine.dispatch``, ``engine.fetch``,
+        ``engine.sample``, see ``_run_packed``) or ``engine.run_two_call``,
+        ``engine.prefetch`` and ``sched.complete_step``."""
+        tr, acc = self.trace, self._spans
+        step = self.steps_run
+        with span("engine.step", LANE_STEP, tr, acc, step_num=step,
+                  step=step) as args:
+            with span("sched.next_step", LANE_SCHED, tr, acc, step=step):
+                plan = self.scheduler.next_step(now)
+                # even a None plan may have cancelled requests
+                self._purge_released()
+            if plan is None:
+                return None
+            args.update(tokens=plan.total_tokens,
+                        decodes=len(plan.decode_rids),
+                        prefill_tokens=plan.total_prefill_tokens)
+            if plan.prefetch is not None:
+                self.prefetch_log.append(plan.prefetch.coverage)
+            # copy-on-write page duplication for mid-block prefix resumes
+            # MUST run before any other device write this step: sources are
+            # cached pages whose ids were valid at plan time, and neither
+            # swap traffic nor the compute scatter has touched the pool yet
+            with span("engine.prefix_copies", LANE_COMPUTE, tr, acc,
+                      step=step):
+                self._apply_prefix_copies(plan)
+            with span("engine.swaps", LANE_HOST_LINK, tr, acc, step=step,
+                      swap_out=len(plan.swapped_out),
+                      swap_in=len(plan.swapped_in)):
+                self._apply_swaps(plan)
+                self._verify_landed(plan)
+            if self.packed_mode:
+                self._run_packed(plan)
+            else:
+                with span("engine.run_two_call", LANE_COMPUTE, tr, acc,
+                          step=step, tokens=plan.total_tokens):
+                    self._run_two_call(plan)
+            # stage next step's predicted transfers NOW: the compute above
+            # is dispatched but (on an async backend) still in flight, so
+            # these host->device copies ride under it
+            with span("engine.prefetch", LANE_PREFETCH_STAGE, tr, acc,
+                      step=step, issued=len(plan.issued)):
+                self._issue_prefetch(plan)
+            with span("sched.complete_step", LANE_SCHED, tr, acc, step=step):
+                self.scheduler.complete_step(plan, now)
+                # emit the step's attribution instant at the same point in
+                # the event stream as the sim (right after complete_step),
+                # so the two backends' sched sequences stay position-aligned
+                # for --compare
+                self.scheduler.ledger.record_step(tr, plan.step)
+            self.steps_run += 1
         return plan
 
     # ----------------------------------------------------------------- swaps
@@ -727,81 +772,100 @@ class Engine:
         return min(nb, self.pages_per_slot)
 
     def _run_packed(self, plan: StepPlan) -> None:
+        """One packed step in four spans: ``engine.pack`` builds the host
+        arrays (and re-syncs the block mirror), ``engine.dispatch`` enqueues
+        the jitted step and the sampled-row gather, ``engine.fetch`` waits
+        for the sampled rows on the host (the device step ends inside it)
+        and ``engine.sample`` picks and appends the tokens."""
+        tr, acc = self.trace, self._spans
+        step = self.steps_run
         sch = self.scheduler
-        N = self.bucket
-        tokens = np.zeros((N,), np.int32)
-        slots = np.full((N,), self.n_slots, np.int32)  # scratch by default
-        positions = np.zeros((N,), np.int32)
+        with span("engine.pack", LANE_HOST, tr, acc, step=step):
+            N = self.bucket
+            tokens = np.zeros((N,), np.int32)
+            slots = np.full((N,), self.n_slots, np.int32)  # scratch by default
+            positions = np.zeros((N,), np.int32)
 
-        nd = len(plan.decode_slots)
-        for i, (slot, rid) in enumerate(zip(plan.decode_slots, plan.decode_rids)):
-            req = sch.requests[rid]
-            tokens[i] = req.output[-1]
-            positions[i] = req.next_decode_pos
-            slots[i] = slot
-        row = nd
-        last_rows = {}  # rid -> row of its segment's last token (finishing only)
-        for seg in plan.prefill_segments:
-            req = sch.requests[seg.rid]
-            tokens[row : row + seg.length] = req.prefill_slice(seg.start, seg.length)
-            positions[row : row + seg.length] = np.arange(seg.start, seg.start + seg.length)
-            slots[row : row + seg.length] = seg.slot
-            if seg.finishes:
-                last_rows[seg.rid] = row + seg.length - 1
-            row += seg.length
+            nd = len(plan.decode_slots)
+            for i, (slot, rid) in enumerate(zip(plan.decode_slots, plan.decode_rids)):
+                req = sch.requests[rid]
+                tokens[i] = req.output[-1]
+                positions[i] = req.next_decode_pos
+                slots[i] = slot
+            row = nd
+            last_rows = {}  # rid -> row of its segment's last token (finishing only)
+            for seg in plan.prefill_segments:
+                req = sch.requests[seg.rid]
+                tokens[row : row + seg.length] = req.prefill_slice(seg.start, seg.length)
+                positions[row : row + seg.length] = np.arange(seg.start, seg.start + seg.length)
+                slots[row : row + seg.length] = seg.slot
+                if seg.finishes:
+                    last_rows[seg.rid] = row + seg.length - 1
+                row += seg.length
 
-        if self.attn_kernel == "paged":
-            max_ctx = self._sync_block_mirror(plan)
-            nb = self._nb_bucket(max_ctx)
-            bt = jnp.asarray(self.block_mirror[:, :nb])
-            # segment layout for the unified mixed-batch attention call:
-            # the scheduler stamped cu_q_lens/cu_kv_lens on the plan in the
-            # SAME order the rows above were packed (decodes, then prefill
-            # segments), so the arrays ship verbatim — single source of
-            # truth shared with the sim's cost model. Padding segments are
-            # zero-width (q_len = kv_len = 0) and own the scratch slot.
-            s_real = nd + len(plan.prefill_segments)
-            kv_real = plan.kv_lens
-            sb = 8
-            while sb < s_real:
-                sb *= 2
-            cu_q = np.full((sb + 1,), plan.cu_q_lens[-1], np.int32)
-            cu_q[: s_real + 1] = plan.cu_q_lens
-            kv_lens = np.zeros((sb,), np.int32)
-            kv_lens[:s_real] = kv_real
-            seg_slots = np.full((sb,), self.n_slots, np.int32)
-            seg_slots[:nd] = plan.decode_slots
-            for i, seg in enumerate(plan.prefill_segments):
-                seg_slots[nd + i] = seg.slot
-            # static q-block: pow2 bucket of the longest segment so a
-            # decode-only step compiles with qb=1 while chunked prefills
-            # tile in blocks — (nb, sb, qb) are the only shape-bearing keys
-            qb = 1
-            max_q = int(max(np.diff(cu_q[: s_real + 1]), default=1))
-            while qb < max_q:
-                qb *= 2
-            assert int(cu_q[s_real]) == row, (
-                f"plan row layout mismatch: cu_q_lens end {cu_q[s_real]} "
-                f"!= packed rows {row}")
-            if nd:
-                assert np.array_equal(kv_lens[:nd], positions[:nd] + 1), (
-                    "decode kv_lens drifted from engine positions")
-            logits, self.cache = self._packed(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(slots),
-                jnp.asarray(positions), bt, jnp.asarray(cu_q),
-                jnp.asarray(kv_lens), jnp.asarray(seg_slots), qb,
-            )
-        else:
-            logits, self.cache = self._packed(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(slots),
-                jnp.asarray(positions),
-            )
-        # one device->host transfer of just the sampled rows, then one
-        # vectorized argmax (greedy) over all of them
-        rows = list(range(nd)) + list(last_rows.values())
-        rids = list(plan.decode_rids) + list(last_rows.keys())
-        if rows:
-            picked = np.asarray(logits[jnp.asarray(rows, jnp.int32)])
+            paged = self.attn_kernel == "paged"
+            if paged:
+                max_ctx = self._sync_block_mirror(plan)
+                nb = self._nb_bucket(max_ctx)
+                # segment layout for the unified mixed-batch attention call:
+                # the scheduler stamped cu_q_lens/cu_kv_lens on the plan in
+                # the SAME order the rows above were packed (decodes, then
+                # prefill segments), so the arrays ship verbatim — single
+                # source of truth shared with the sim's cost model. Padding
+                # segments are zero-width (q_len = kv_len = 0) and own the
+                # scratch slot.
+                s_real = nd + len(plan.prefill_segments)
+                kv_real = plan.kv_lens
+                sb = 8
+                while sb < s_real:
+                    sb *= 2
+                cu_q = np.full((sb + 1,), plan.cu_q_lens[-1], np.int32)
+                cu_q[: s_real + 1] = plan.cu_q_lens
+                kv_lens = np.zeros((sb,), np.int32)
+                kv_lens[:s_real] = kv_real
+                seg_slots = np.full((sb,), self.n_slots, np.int32)
+                seg_slots[:nd] = plan.decode_slots
+                for i, seg in enumerate(plan.prefill_segments):
+                    seg_slots[nd + i] = seg.slot
+                # static q-block: pow2 bucket of the longest segment so a
+                # decode-only step compiles with qb=1 while chunked prefills
+                # tile in blocks — (nb, sb, qb) are the only shape-bearing
+                # keys
+                qb = 1
+                max_q = int(max(np.diff(cu_q[: s_real + 1]), default=1))
+                while qb < max_q:
+                    qb *= 2
+                assert int(cu_q[s_real]) == row, (
+                    f"plan row layout mismatch: cu_q_lens end {cu_q[s_real]} "
+                    f"!= packed rows {row}")
+                if nd:
+                    assert np.array_equal(kv_lens[:nd], positions[:nd] + 1), (
+                        "decode kv_lens drifted from engine positions")
+            rows = list(range(nd)) + list(last_rows.values())
+            rids = list(plan.decode_rids) + list(last_rows.keys())
+        with span("engine.dispatch", LANE_COMPUTE, tr, acc, step=step,
+                  tokens=plan.total_tokens):
+            if paged:
+                logits, self.cache = self._packed(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(slots), jnp.asarray(positions),
+                    jnp.asarray(self.block_mirror[:, :nb]), jnp.asarray(cu_q),
+                    jnp.asarray(kv_lens), jnp.asarray(seg_slots), qb,
+                )
+            else:
+                logits, self.cache = self._packed(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(slots), jnp.asarray(positions),
+                )
+            # one device->host transfer of just the sampled rows, then one
+            # vectorized argmax (greedy) over all of them
+            if rows:
+                picked = logits[jnp.asarray(rows, jnp.int32)]
+        if not rows:
+            return
+        with span("engine.fetch", LANE_COMPUTE, tr, acc, step=step):
+            picked = np.asarray(picked)
+        with span("engine.sample", LANE_HOST, tr, acc, step=step):
             for rid, tok in zip(rids, self._sample_rows(picked)):
                 self._append(sch.requests[rid], int(tok))
 

@@ -23,6 +23,7 @@ import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config, reduce_config
@@ -394,3 +395,200 @@ def test_chrome_trace_is_loadable_object_form(tmp_path):
     names = [e for e in trace["traceEvents"]
              if e["ph"] == "M" and e["name"] == "thread_name"]
     assert names, "no thread_name metadata — Perfetto rows would be unnamed"
+
+
+# ---------------------------------------------------------------------------
+# host spans (obs.trace.span) and the engine's step phases
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = ("sched.next_step", "engine.prefix_copies", "engine.swaps",
+               "engine.pack", "engine.dispatch", "engine.fetch",
+               "engine.sample", "engine.prefetch", "sched.complete_step")
+
+
+class _Refuses:
+    """A disabled recorder that fails on any recording call."""
+
+    enabled = False
+
+    def now(self):
+        raise AssertionError("a disabled recorder's clock was read")
+
+    def span(self, *a, **k):
+        raise AssertionError("a disabled recorder recorded a span")
+
+
+def test_span_nests_and_accumulates():
+    import time
+
+    from repro.obs.trace import span
+
+    tr = TraceRecorder("t")
+    acc = ({}, {})
+    for i in range(3):
+        with span("outer", "step", tr, acc, step_num=i, step=i) as args:
+            with span("inner", "host", tr, acc, step=i):
+                time.sleep(0.002)
+            args["tokens"] = 7
+    secs, calls = acc
+    assert calls == {"outer": 3, "inner": 3}
+    assert secs["outer"] >= secs["inner"] >= 0.006
+    outer = [e for e in tr.events if e.name == "outer"]
+    inner = [e for e in tr.events if e.name == "inner"]
+    assert [e.step for e in outer] == [0, 1, 2]
+    assert all(e.lane == "step" and e.args == {"tokens": 7} for e in outer)
+    for o, n in zip(outer, inner):  # the inner span lies inside the outer
+        assert o.ts <= n.ts and n.ts + n.dur <= o.ts + o.dur + 1e-9
+
+
+def test_span_counts_without_recording_when_disabled():
+    from repro.obs.trace import span
+
+    acc = ({}, {})
+    with span("a", "host", _Refuses(), acc):
+        pass
+    with span("a", "host", NOOP, acc, step=1):
+        pass
+    assert acc[1] == {"a": 2} and acc[0]["a"] >= 0.0
+
+
+def test_span_counts_when_the_body_raises():
+    from repro.obs.trace import span
+
+    acc = ({}, {})
+    with pytest.raises(ValueError):
+        with span("a", "host", NOOP, acc):
+            raise ValueError
+    assert acc[1] == {"a": 1}
+
+
+def test_chrome_export_carries_both_clock_origins(tmp_path):
+    import time
+
+    t, ns = time.perf_counter(), time.time_ns()
+    tr = TraceRecorder("engine")
+    path = tmp_path / "t.json"
+    export_chrome(tr, str(path))
+    other = json.loads(path.read_text())["otherData"]
+    assert other["origin_perf_counter_s"] == tr.origin[0] >= t
+    assert other["origin_unix_ns"] == tr.origin[1] >= ns
+
+
+@pytest.fixture(scope="module")
+def engine_run(tmp_path_factory):
+    """The swap workload through a tiny paged engine, recorded by a
+    TraceRecorder and by the JAX profiler (on the CPU)."""
+    from jax.profiler import ProfileData
+
+    from repro.models import build_model
+    from repro.obs import MetricsRegistry
+    from repro.serving.engine import Engine
+
+    cfg = reduce_config(get_config("llama3.1-8b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    tr = TraceRecorder("engine")
+    eng = Engine(model, params, SchedulerConfig(async_prefetch=True,
+                                                **SWAP_KNOBS),
+                 max_len=64, tracer=tr)
+    for r in swap_requests():
+        eng.submit(r)
+    eng.step()  # compile outside the profiled stretch
+    out = tmp_path_factory.mktemp("engine_run")
+    jax.profiler.start_trace(str(out / "prof"))
+    eng.run(max_steps=500)
+    jax.profiler.stop_trace()
+    eng.scheduler.ledger.record_totals(tr, eng.attribution_aggregates())
+    export_chrome(tr, str(out / "trace.json"))
+    reg = MetricsRegistry()
+    eng.register_metrics(reg)
+    pb = next((out / "prof").rglob("*.xplane.pb"))
+    host = [(e.start_ns, e.end_ns, e.name)
+            for p in ProfileData.from_file(str(pb)).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events
+            if e.name.startswith(("engine.", "sched."))]
+    return eng, tr, out / "trace.json", reg, host
+
+
+def test_engine_phases_tile_the_step(engine_run):
+    eng, _, _, _, _ = engine_run
+    assert eng.steps_run > 10
+    assert set(eng.span_s) == {"engine.step", *STEP_PHASES}
+    assert eng.span_n["engine.step"] >= eng.steps_run
+    assert eng.span_n["sched.next_step"] == eng.span_n["engine.step"]
+    inner = sum(eng.span_s[p] for p in STEP_PHASES)
+    assert 0.9 * eng.span_s["engine.step"] <= inner <= eng.span_s["engine.step"]
+
+
+def test_engine_trace_export_passes_the_checker(engine_run):
+    _, tr, path, _, _ = engine_run
+    r = run_checker(path)
+    assert r.returncode == 0, r.stderr
+    names = {e.name for e in tr.events if e.ph == "X" and e.lane != "request"}
+    assert names == {"engine.step", *STEP_PHASES}
+
+
+def test_engine_span_counters_are_registered(engine_run):
+    eng, _, _, reg, _ = engine_run
+    flat = reg.as_dict()
+    for name in ("engine.step", *STEP_PHASES):
+        assert flat[f"span_s.{name}"] == eng.span_s[name]
+        assert flat[f"span_n.{name}"] == eng.span_n[name]
+    assert reg.get("span_s.engine.step").unit == "s"
+    assert reg.get("span_n.engine.step").unit == "calls"
+
+
+def test_engine_phases_nest_in_the_profiler_step(engine_run):
+    eng, _, _, _, host = engine_run
+    steps = sorted((a, b) for a, b, n in host if n == "engine.step")
+    assert len(steps) >= eng.steps_run - 1
+    seen = set()
+    for a, b, name in host:
+        if name == "engine.step":
+            continue
+        # every phase lies inside one engine.step of the profiler's trace
+        assert any(s <= a and b <= e for s, e in steps), name
+        seen.add(name)
+    assert seen == set(STEP_PHASES)
+
+
+def test_step_op_scopes_name_every_op_of_the_step():
+    import re
+
+    from repro.core.packed_step import STEP_SCOPES
+    from repro.models import build_model
+    from repro.serving.engine import Engine
+
+    cfg = reduce_config(get_config("qwen3-32b"), periods=3)  # a scanned stack
+    model = build_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)),
+                 SchedulerConfig(chunk_size=16, max_decode_batch=3,
+                                 kv_block_size=4), max_len=64)
+    scopes = eng.step_op_scopes(8, 8, 16)
+    assert set(scopes.values()) == set(STEP_SCOPES)
+    text = eng._packed.lower(
+        *jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                      (eng.params, eng.cache)),
+        *[jax.ShapeDtypeStruct(s, jnp.int32) for s in
+          [(eng.bucket,)] * 3 + [(eng.n_slots + 1, 8), (9,), (8,), (8,)]],
+        16).compile().as_text()
+    assert text.startswith("HloModule jit_paged_step")
+    op = re.compile(r'^\s*(?:ROOT\s+)?%(\S+) = .*? ([a-z\-]+)\(.*'
+                    r'op_name="jit\(paged_step\)/([^"]*)"')
+    pool_ops = 0
+    for line in text.splitlines():
+        m = op.match(line)
+        if not m or m.group(2) in ("parameter", "constant", "bitcast"):
+            continue
+        name, kind, path = m.groups()
+        # the scan's own machinery (the loop, its counter, the slicing of
+        # its stacked weights) is all that may go unnamed
+        if re.fullmatch(r"while(/(body|cond)/[a-z_]+)?", path):
+            continue
+        assert name in scopes, (name, path)
+        if kind in ("dynamic-slice", "dynamic-update-slice") and (
+                "closed_call/kv_pool" in path):
+            assert scopes[name] == "kv_pool"
+            pool_ops += 1
+    assert pool_ops >= 2  # the layer's slice of the pool and its write-back
