@@ -70,7 +70,8 @@ class PagedView:
     slot), carrying the allocator's **actual** page ids, already sliced to
     ``nb`` columns where ``nb * page_size`` covers the longest live context
     this step. Dead entries point at the scratch page, so every id is a
-    valid pool index even for grid steps the kernel skips.
+    valid pool index for the paths that gather whole tables (the jnp
+    oracle, the latent branch); the kernel never reads them.
 
     The segment layout (``cu_q_lens`` / ``kv_lens`` / ``seg_slots``) carries
     the step's mixed batch: decode rows first (one 1-token segment each),

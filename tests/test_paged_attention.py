@@ -14,11 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _compat import given, settings, st
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs import get_config, reduce_config
 from repro.core.scheduler import SchedulerConfig
 from repro.kernels import ops, ref
-from repro.kernels.paged_attention import tokens_touched
+from repro.kernels.paged_attention import (
+    _block_copies, _block_runs, kv_block_pages, mixed_grid, tokens_touched,
+)
 from repro.models import build_model
 from repro.serving.engine import Engine
 from repro.serving.request import Request
@@ -43,15 +46,18 @@ def dense_to_pool(k, page):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("H,KV", [(4, 4), (8, 2), (4, 1)])  # MHA / GQA 4x / MQA
-@pytest.mark.parametrize("page", [32, 64])
+# MHA / GQA 4x, 6x, 8x / MQA
+@pytest.mark.parametrize("H,KV", [(4, 4), (8, 2), (12, 2), (16, 2), (4, 1)])
+@pytest.mark.parametrize("page", [16, 32, 64])  # 8 / 4 / 2 pages a KV block
 def test_paged_matches_decode_ref_ragged(H, KV, page):
-    B, S, d = 4, 256, 64
+    B, S, d = 5, 256, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = rand(ks[0], (B, H, d))
     k = rand(ks[1], (B, S, KV, d))
     v = rand(ks[2], (B, S, KV, d))
-    lengths = jnp.array([1, 37, page, S], jnp.int32)  # ragged incl. extremes
+    # ragged incl. extremes; 37 ends mid-block, 133 in the second 128-key
+    # block's first page
+    lengths = jnp.array([1, 37, page, 133, S], jnp.int32)
     pool_k, tables = dense_to_pool(k, page)
     pool_v, _ = dense_to_pool(v, page)
     expect = ref.decode_attention_ref(
@@ -65,7 +71,7 @@ def test_paged_matches_decode_ref_ragged(H, KV, page):
         )
 
 
-@pytest.mark.parametrize("window", [None, 48])
+@pytest.mark.parametrize("window", [None, 48, 140])  # both straddle key 128
 @pytest.mark.parametrize("softcap", [None, 30.0])
 def test_paged_window_softcap(window, softcap):
     B, H, KV, S, d, page = 3, 4, 2, 256, 32, 64
@@ -116,15 +122,18 @@ def test_paged_block_table_permutation():
         )
 
 
-def test_paged_tail_entries_never_read():
+@pytest.mark.parametrize("page", [16, 64])
+def test_paged_tail_entries_never_read(page):
     """Table entries past ceil(length/page) may point anywhere valid —
-    corrupting those pages must not change the output."""
-    B, H, KV, S, d, page = 2, 4, 2, 256, 32, 64
+    corrupting those pages must not change the output, also where they
+    share a KV block with live pages."""
+    B, H, KV, S, d = 2, 4, 2, 256, 32
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = rand(ks[0], (B, H, d))
     k = rand(ks[1], (B, S, KV, d))
     v = rand(ks[2], (B, S, KV, d))
-    lengths = jnp.array([40, 70], jnp.int32)  # 1 / 2 live pages of 4
+    # page 64: 1 / 2 live pages of 4; page 16: 3 / 5 of 16, all in block 0
+    lengths = jnp.array([40, 70], jnp.int32)
     pool_k, tables = dense_to_pool(k, page)
     pool_v, _ = dense_to_pool(v, page)
     out1 = ops.paged_attention_rows(q, pool_k, pool_v, lengths, tables, interpret=True)
@@ -146,6 +155,52 @@ def test_tokens_touched_accounting():
     touched = tokens_touched(lengths, page)
     assert touched == 32 + 64 + 64 + 128
     assert touched < len(lengths) * s_max
+
+
+@pytest.mark.parametrize("nb", [8, 64, 1024, 2048])
+def test_mixed_grid_block_of_pages(nb):
+    """One grid step per 128-key block: at page 16 and nb >= 8 the kernel
+    takes 8x fewer grid steps than one per page (8,192 instead of 65,536
+    per call at the qwen3-32b-l8 cell's S 8, KV 8, nb 1024); larger pages
+    take fewer pages a block, and the block never exceeds the table."""
+    S, KV = 8, 8
+    assert kv_block_pages(nb, 16) == 8
+    assert np.prod(mixed_grid(S, KV, nb, 16)) * 8 == S * KV * nb
+    assert mixed_grid(S, KV, nb, 32) == (S, KV, nb // 4)
+    assert mixed_grid(S, KV, nb, 256) == (S, KV, nb)
+    assert mixed_grid(S, KV, 4, 16) == (S, KV, 1)  # capped at nb
+    assert mixed_grid(S, KV, 12, 16) == (S, KV, 2)  # a partial last block
+    if nb == 1024:
+        assert np.prod(mixed_grid(S, KV, nb, 16)) == 8192
+
+
+@pytest.mark.parametrize("window", [None, 1, 5, 20])
+def test_mixed_block_copies_start_each_running_block_once(window):
+    """The kernel's block skipping and double-buffer schedule, brute-forced:
+    a block runs exactly when some query of the segment attends one of its
+    keys, and each running block's copies are started exactly once (a
+    second start would leave its semaphore signalled into the next wait),
+    none for a skipped block."""
+    blk, nbb = 8, 6
+    for kv_len in range(nbb * blk + 1):
+        for q_len in {0, 1, 3, 8, kv_len}:
+            if q_len > kv_len:
+                continue
+            runs = lambda b: _block_runs(b, blk=blk, kv_len=kv_len,
+                                         q_len=q_len, window=window)
+            starts = [0] * (nbb + 1)
+            for ib in range(nbb):
+                own, nxt = _block_copies(ib, nbb, runs)
+                starts[ib] += bool(own)
+                starts[ib + 1] += bool(nxt)
+            for b in range(nbb):
+                attended = any(
+                    k <= q_pos and (window is None or k > q_pos - window)
+                    for q_pos in range(kv_len - q_len, kv_len)
+                    for k in range(b * blk, (b + 1) * blk))
+                assert bool(runs(b)) == attended, (kv_len, q_len, b)
+                assert starts[b] == attended, (kv_len, q_len, b)
+            assert starts[nbb] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -283,33 +338,17 @@ def test_engine_block_mirror_lifecycle():
 # ---------------------------------------------------------------------------
 
 
-def _mixed_case(data, st):
-    """Draw a random mixed batch: decode rows, prefill chunks, and dead
-    zero-width segments over a shuffled page pool with corrupted dead pages.
-    Returns everything both attention paths need plus the per-token
-    expansion the OLD per-row path consumes."""
-    rng = np.random.default_rng(data.draw(st.integers(0, 2**31 - 1)))
-    KV = data.draw(st.sampled_from([1, 2]))
-    G = data.draw(st.sampled_from([1, 2, 4]))
-    d, page = 32, 8
-    n_seg = data.draw(st.integers(1, 4))
-    segs = []  # (q_len, kv_len)
-    for _ in range(n_seg):
-        kind = data.draw(st.sampled_from(["decode", "chunk", "chunk", "dead"]))
-        if kind == "decode":
-            segs.append((1, data.draw(st.integers(1, 40))))
-        elif kind == "chunk":
-            q_len = data.draw(st.integers(2, 6))
-            segs.append((q_len, data.draw(st.integers(q_len, 40))))
-        else:
-            segs.append((0, 0))
-    pad = data.draw(st.integers(0, 3))
-    window = data.draw(st.sampled_from([None, 5, 16]))
-    softcap = data.draw(st.sampled_from([None, 20.0]))
-
-    nb = max((-(-kv // page) for _, kv in segs), default=1) + 1
-    nb = max(nb, 2)
+def _mixed_case(rng, *, KV, G, page, segs, pad, window, softcap, nb=None):
+    """A mixed batch of decode rows, prefill chunks and dead zero-width
+    segments (``segs``: (q_len, kv_len) each) over a shuffled page pool
+    whose dead pages are corrupted. ``nb`` defaults to one column past the
+    longest table. Returns everything both attention paths need plus the
+    per-token expansion the OLD per-row path consumes."""
+    d = 32
+    n_seg = len(segs)
     live_per_seg = [-(-kv // page) for _, kv in segs]
+    if nb is None:
+        nb = max(max(live_per_seg, default=1) + 1, 2)
     P = sum(live_per_seg) + 4  # + dead garbage pages
     page_ids = rng.permutation(P)
     dead = list(page_ids[sum(live_per_seg):])
@@ -345,15 +384,36 @@ def _mixed_case(data, st):
                 row_tab=np.asarray(row_tab, np.int32).reshape(len(row_tab), nb))
 
 
-@settings(deadline=None, max_examples=15)
-@given(data=st.data())
-def test_mixed_matches_per_token_path(data):
-    """Property parity: the unified mixed-batch attention (jnp oracle AND
-    Pallas kernel in interpret mode) equals the OLD per-token ragged path on
-    random decode/prefill mixes — shuffled non-contiguous tables, GQA,
-    window, softcap, zero-width segments, and corrupted dead pages that must
-    never be read."""
-    c = _mixed_case(data, st)
+def _draw_mixed_case(data, st):
+    """Random mixed batch: contexts up to 300 keys, so KV blocks of 128 keys
+    end mid-table, and ``nb`` either tight or the engine's pow2 bucket."""
+    rng = np.random.default_rng(data.draw(st.integers(0, 2**31 - 1)))
+    page = data.draw(st.sampled_from([8, 16, 32]))
+    segs = []  # (q_len, kv_len)
+    for _ in range(data.draw(st.integers(1, 4))):
+        kind = data.draw(st.sampled_from(["decode", "chunk", "chunk", "dead"]))
+        if kind == "decode":
+            segs.append((1, data.draw(st.integers(1, 300))))
+        elif kind == "chunk":
+            q_len = data.draw(st.integers(2, 6))
+            segs.append((q_len, data.draw(st.integers(q_len, 300))))
+        else:
+            segs.append((0, 0))
+    nb = None
+    if data.draw(st.booleans()):
+        nb = 8
+        while nb * page < max(kv for _, kv in segs):
+            nb *= 2
+    return _mixed_case(
+        rng, KV=data.draw(st.sampled_from([1, 2])),
+        G=data.draw(st.sampled_from([1, 2, 4, 6, 8])), page=page, segs=segs,
+        pad=data.draw(st.integers(0, 3)),
+        window=data.draw(st.sampled_from([None, 5, 16, 130])),
+        softcap=data.draw(st.sampled_from([None, 20.0])), nb=nb)
+
+
+def _check_mixed_matches_per_token_path(c, modes=(dict(use_kernel=False),
+                                                 dict(interpret=True))):
     if c["n_real"] == 0:
         return  # all segments dead: nothing to compare
     expect = ops.paged_attention_rows(
@@ -361,7 +421,7 @@ def test_mixed_matches_per_token_path(data):
         jnp.asarray(c["pool_v"]), jnp.asarray(c["row_len"]),
         jnp.asarray(c["row_tab"]), window=c["window"], softcap=c["softcap"],
     )
-    for kwargs in (dict(use_kernel=False), dict(interpret=True)):
+    for kwargs in modes:
         got = ops.mixed_attention_rows(
             jnp.asarray(c["q"]), jnp.asarray(c["pool_k"]),
             jnp.asarray(c["pool_v"]), jnp.asarray(c["cu"]),
@@ -373,6 +433,52 @@ def test_mixed_matches_per_token_path(data):
             rtol=2e-5, atol=2e-5,
         )
         assert np.all(np.isfinite(np.asarray(got)))
+
+
+@settings(deadline=None, max_examples=15)
+@given(data=st.data())
+def test_mixed_matches_per_token_path(data):
+    """Property parity: the unified mixed-batch attention (jnp oracle AND
+    Pallas kernel in interpret mode) equals the OLD per-token ragged path on
+    random decode/prefill mixes — shuffled non-contiguous tables, GQA,
+    window, softcap, zero-width segments, and corrupted dead pages that must
+    never be read."""
+    _check_mixed_matches_per_token_path(_draw_mixed_case(data, st))
+
+
+# the kernel's KV-block edges, pinned: (KV, G, page, nb, segs, pad, window)
+BLOCK_EDGE_CASES = {
+    # one block covers the whole 8-column table; 128 ends on its edge
+    "nb_is_one_block": (1, 8, 16, 8, [(1, 100), (6, 128), (0, 0)], 2, None),
+    # 4-page blocks; 200 ends mid-block, 129 in block 1's first page
+    "page32_block_ends": (2, 6, 32, 8, [(1, 200), (4, 129), (0, 0)], 1, None),
+    # the chunk's window starts in block 2 (blocks 0-1 skipped, block 2
+    # fetches itself); the decode's window straddles key 128
+    "window_over_block_edge": (1, 8, 16, 32, [(3, 300), (1, 140)], 0, 40),
+    # zero-width padding segments around live ones, and padding rows
+    "padding_segments": (2, 6, 16, 16, [(0, 0), (5, 133), (0, 0), (1, 17), (0, 0)],
+                         3, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_EDGE_CASES))
+def test_mixed_block_edges_match_per_token_path(case):
+    """The property above at the KV-block edges of the block-per-step grid,
+    also under the TPU interpreter, which models the kernel's page DMAs and
+    semaphores, fills never-written VMEM with NaN and raises on a read out
+    of a buffer's bounds."""
+    KV, G, page, nb, segs, pad, window = BLOCK_EDGE_CASES[case]
+    c = _mixed_case(
+        np.random.default_rng(0), KV=KV, G=G, page=page, segs=segs, pad=pad,
+        window=window, softcap=None, nb=nb)
+    tpu_interpret = dict(interpret=pltpu.InterpretParams())
+    _check_mixed_matches_per_token_path(
+        c, modes=(dict(use_kernel=False), dict(interpret=True), tpu_interpret))
+    # table entries past each segment's pages, even those sharing a block
+    # with live pages, are never fetched: out of the pool, they would raise
+    dead = np.arange(nb)[None, :] >= -(-c["kv_lens"][:, None] // page)
+    c["tables"] = np.where(dead, c["pool_k"].shape[0] + 1000, c["tables"])
+    _check_mixed_matches_per_token_path(c, modes=(tpu_interpret,))
 
 
 def test_engine_mixed_swap_oversubscribed_token_identity():

@@ -59,6 +59,8 @@ CASES = {
     "llama8b_mixed": (8, 4, 128, 16, 32, 16, 512, None, None),
     "llama8b_nb2048": (8, 4, 128, 16, 32, 40, 2048, None, None),
     "gemma2b_window_softcap": (4, 2, 256, 16, 32, 16, 512, 4096, 50.0),
+    "qwen3_32b_mixed": (8, 8, 128, 16, 128, 8, 1024, None, None),
+    "qwen3_32b_nb2048": (8, 8, 128, 16, 128, 8, 2048, None, None),
 }
 
 
