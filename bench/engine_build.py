@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# configuration-file key -> ModelConfig field the program must agree on
+# configuration-file key -> ModelConfig field the program must agree on; a
+# file's null (a width the model does not have) agrees with the program's 0
 WIDTHS = {
     "hidden_size": "d_model", "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
@@ -20,6 +21,14 @@ WIDTHS = {
     "num_hidden_layers": "n_layers", "qk_norm": "qk_norm",
     "attention_bias": "qkv_bias", "tie_word_embeddings": "tie_embeddings",
     "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    # sparse experts
+    "n_routed_experts": "n_experts", "num_experts": "n_experts",
+    "num_experts_per_tok": "top_k", "moe_intermediate_size": "moe_d_ff",
+    "n_shared_experts": "n_shared_experts",
+    # latent attention
+    "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
 }
 
 
@@ -42,7 +51,7 @@ def program_config(cfg: dict):
 
 def check_widths(cfg: dict, mc) -> None:
     bad = {k: (cfg[k], getattr(mc, f)) for k, f in WIDTHS.items()
-           if k in cfg and cfg[k] != getattr(mc, f)}
+           if k in cfg and (0 if cfg[k] is None else cfg[k]) != getattr(mc, f)}
     if bad:
         raise ValueError(f"{cfg['name']}: program config differs from the file "
                          f"(file, program): {bad}")

@@ -18,6 +18,13 @@ the reference computed in fp8 in the program's place for the comparison: the
 tokens it picks first are judged instead of the served ones, and the run
 reads ``correct`` false (how a limit's upper reading is taken).
 
+A reader gets what it reads from the run record (``Run``): the engine's
+span totals and the counters it registers (``Engine.register_metrics``),
+taken over the window; in traced runs the trace's busy time, the device
+seconds of every kernel a per-layer reader names in its ``KERNEL``, and the
+device seconds by the packed step's named scopes. So a new per-layer metric
+is one new reader file.
+
 Without a TPU, or with fewer chips than the cell asks for, it exits 3 and
 prints no result. JAX's compilation cache lives in ``<checkout>/.jax_cache``
 unless ``JAX_COMPILATION_CACHE_DIR`` names another directory.
@@ -43,7 +50,6 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 TRACE_S = 5.0  # profiled seconds, centred in the window
-KERNELS = ("mixed_paged_attention",)  # kernels whose device time the trace sums
 QUIET_S = 5.0  # warm-up ends after this long without a compile
 STRETCH_S = 10.0  # output tokens/s is logged by stretches of this length
 
@@ -54,7 +60,12 @@ def info(msg: str) -> None:
 
 @dataclass
 class Run:
-    """What one run leaves for the metric readers."""
+    """What one run leaves for the metric readers. ``span_s`` / ``span_n``
+    (the engine's span seconds and calls) and ``counters`` (the flat values
+    of what ``Engine.register_metrics`` registers) are their changes over
+    the window, ``counters_at_close`` the values at its close (gauges).
+    Traced runs only: ``trace`` (``trace_reduce.reduce``) and ``scope_s``
+    (``trace_scopes.scope_seconds``)."""
     cfg: dict
     peaks: dict
     window: tuple
@@ -64,6 +75,40 @@ class Run:
     traced_steps: list = field(default_factory=list)
     trace: dict = None
     compiles_rel: list = field(default_factory=list)
+    span_s: dict = field(default_factory=dict)
+    span_n: dict = field(default_factory=dict)
+    counters: dict = field(default_factory=dict)
+    counters_at_close: dict = field(default_factory=dict)
+    scope_s: dict = None
+
+
+def reader(name: str):
+    return importlib.import_module(f"bench.metrics.{name}")
+
+
+def reader_kernels(names) -> tuple:
+    """The kernels whose device time the readers ``names`` read: each
+    reader's ``KERNEL``, a name or a tuple of names (matched as a part of
+    an operation's name)."""
+    out = []
+    for name in names:
+        k = getattr(reader(name), "KERNEL", ())
+        out += [k] if isinstance(k, str) else list(k)
+    return tuple(dict.fromkeys(out))
+
+
+def engine_counters(eng):
+    """(span seconds, span calls, registered counters) of the engine, read
+    between two steps."""
+    from repro.obs.registry import MetricsRegistry
+
+    reg = MetricsRegistry()
+    eng.register_metrics(reg)
+    return dict(eng.span_s), dict(eng.span_n), reg.as_dict()
+
+
+def change(at_open: dict, at_close: dict) -> dict:
+    return {k: v - at_open.get(k, 0) for k, v in at_close.items()}
 
 
 def load_cell(name: str):
@@ -149,6 +194,9 @@ def main(argv=None, require_tpu: bool = True, fault=None, mc=None, loaded=None):
     w0 = drv.warm_up(int(mix["warmup"]["completions"]), QUIET_S,
                      float(mix["warmup"]["max_s"]))
     setup_s = drv.t0 + w0 - T_START
+    t = time.perf_counter()
+    at_open = engine_counters(eng)
+    read_s = [time.perf_counter() - t]
     w1 = w0 + args.seconds
     n0 = len(drv.steps)
     trace_red, traced = None, (None, None)
@@ -170,6 +218,12 @@ def main(argv=None, require_tpu: bool = True, fault=None, mc=None, loaded=None):
         jax.profiler.stop_trace()
         traced = (tr0, tr1)
     drv.run_until(w1)
+    t = time.perf_counter()
+    at_close = engine_counters(eng)
+    read_s.append(time.perf_counter() - t)
+    info(f"engine spans {len(at_close[0])} and counters {len(at_close[2])} read "
+         f"at the window's open and close in {1e6 * read_s[0]:.0f} and "
+         f"{1e6 * read_s[1]:.0f} us")
     window_steps = drv.steps[n0:]
     lag = np.asarray(drv.lag or [0.0])
     info(f"window [{w0:.3f}, {w1:.3f}] s on the driver clock, steps {len(window_steps)}, "
@@ -199,18 +253,34 @@ def main(argv=None, require_tpu: bool = True, fault=None, mc=None, loaded=None):
     info(f"pool pages used at most {used} of {pages}; peak bytes {peak}, of which "
          f"pool pages never filled {idle_b}, net {peak - idle_b}")
 
+    scope_s = None
     if tmp is not None:
-        from bench import trace_reduce
+        from bench import trace_reduce, trace_scopes
 
+        # after the window: the op-to-scope map of each step shape traced
+        # (compiled from shapes; the step is in the compile cache)
         t = time.perf_counter()
-        path = next(Path(tmp).rglob("*.xplane.pb"))
-        trace_red = trace_reduce.reduce(trace_reduce.load(path), kernels=KERNELS)
+        traced_shapes = sorted({engine_build.shape_of(eng, s.q_lens, s.kv_lens)
+                                for s in drv.steps
+                                if s.t1 > traced[0] and s.t0 < traced[1]})
+        op_scopes = trace_scopes.merge_op_scopes(
+            eng.step_op_scopes(*x) for x in traced_shapes)
+        info(f"op-to-scope maps of the traced shapes {traced_shapes}: {len(op_scopes)} ops "
+             f"in {time.perf_counter() - t:.1f}s, compiles so far {len(compiles)}")
+        t = time.perf_counter()
+        ev = trace_scopes.load(next(Path(tmp).rglob("*.xplane.pb")))
         shutil.rmtree(tmp, ignore_errors=True)
+        trace_red = trace_reduce.reduce(ev, kernels=reader_kernels(layer))
+        scope_s = trace_scopes.scope_seconds(  # the step's compiled module
+            ev, {"jit_" + eng._packed.__name__: op_scopes})
         device["busy_s"] = trace_red["busy_s"]
         device["window_s"] = trace_red["window_s"]
         info(f"trace reduced in {time.perf_counter() - t:.1f}s: window "
              f"{trace_red['window_s']:.4f}s busy {trace_red['busy_s']:.4f}s "
              f"kernels {trace_red['kernel_s']}")
+        info(f"device s by scope: { {k: round(v, 4) for k, v in scope_s.items()} }")
+        info(f"idle s by host span: "
+             f"{ {k: round(v, 4) for k, v in trace_scopes.idle_by_span(ev).items()} }")
 
     run = Run(cfg=cfg, peaks=peaks or {}, window=(w0, w1), setup_s=setup_s,
               recs=drv.recs, window_steps=window_steps,
@@ -218,7 +288,11 @@ def main(argv=None, require_tpu: bool = True, fault=None, mc=None, loaded=None):
                             if traced[0] is not None and s.t0 >= traced[0]
                             and s.t1 <= traced[1]],
               trace=trace_red,
-              compiles_rel=[c - drv.t0 for c in compiles])
+              compiles_rel=[c - drv.t0 for c in compiles],
+              span_s=change(at_open[0], at_close[0]),
+              span_n=change(at_open[1], at_close[1]),
+              counters=change(at_open[2], at_close[2]),
+              counters_at_close=at_close[2], scope_s=scope_s)
     # the requests the window worked on: due in it, or served or admitted in
     # it (a backlog's requests are all due before the window opens)
     inside = lambda t: t is not None and w0 <= t < w1
@@ -229,12 +303,11 @@ def main(argv=None, require_tpu: bool = True, fault=None, mc=None, loaded=None):
     names = layer if args.trace else e2e
     metrics = {}
     for name in names:
-        v = importlib.import_module(f"bench.metrics.{name}").read(run)
+        v = reader(name).read(run)
         if v is not None:
             metrics[name] = {"value": float(v), "unit": metric_specs[name]["unit"]}
     for name in (e2e if args.trace else layer):  # the other kind, for the log
-        v = importlib.import_module(f"bench.metrics.{name}").read(run)
-        info(f"{name}={v}")
+        info(f"{name}={reader(name).read(run)}")
 
     sch = eng.scheduler
     served = [(r.rid, list(sch.requests[r.rid].prompt), list(sch.requests[r.rid].output))

@@ -1,6 +1,9 @@
 """Operation and byte counts checked by hand for one decode-only step and one
-step with a prefill chunk (Qwen3-32B widths, 8 layers)."""
+step with a prefill chunk (Qwen3-32B widths, 8 layers), and handed to the
+configuration's reference module where it states its own."""
 import json
+import sys
+import types
 
 from bench import flops
 from bench.tests.tiny import ROOT
@@ -40,3 +43,37 @@ def test_chunk_step():
     w = flops.layer_matmul_params(CFG)
     assert flops.step_flops(CFG, q, kv, sampled=1) == (
         8 * (2 * w * 257 + 4 * 64 * 128 * keys) + 2 * 5120 * 151936)
+
+
+def test_dense_gqa_states_no_counts_of_its_own():
+    from bench.references import dense_gqa
+
+    assert not any(hasattr(dense_gqa, n) for n in (
+        "layer_matmul_params", "attention_flops", "attention_bytes"))
+    # the counts of a mixed step as they were before counts could be handed
+    # to the reference module
+    q, kv = (1, 1, 1, 128), (7000, 300, 12000, 4096)
+    assert flops.layer_matmul_params(CFG) == 487587840
+    assert flops.attention_flops(CFG, q, kv) == 17545953280.0
+    assert flops.attention_bytes(CFG, q, kv, 16) == 100171776.0
+    assert flops.step_flops(CFG, q, kv, sampled=3) == 1167019212800.0
+
+
+def test_reference_module_states_its_own_counts(monkeypatch):
+    stub = types.ModuleType("bench.references.stub_moe")
+    stub.layer_matmul_params = lambda cfg: 1000  # e.g. active experts only
+    monkeypatch.setitem(sys.modules, stub.__name__, stub)
+    cfg = dict(CFG, reference="stub_moe")
+    q, kv = (1, 256), (40, 768)
+    w = flops.layer_matmul_params(CFG)
+    assert flops.layer_matmul_params(cfg) == 1000
+    assert flops.step_flops(cfg, q, kv, 1) == (
+        flops.step_flops(CFG, q, kv, 1) - 8 * 2 * (w - 1000) * 257)
+    # what the module does not state is the dense count
+    assert flops.attention_flops(cfg, q, kv) == flops.attention_flops(CFG, q, kv)
+    stub.attention_flops = lambda cfg, q_lens, kv_lens: 5.0 * sum(kv_lens)
+    stub.attention_bytes = lambda cfg, q_lens, kv_lens, page: 3.0 * page
+    assert flops.attention_flops(cfg, q, kv) == 5.0 * 808
+    assert flops.attention_bytes(cfg, q, kv, 16) == 48.0
+    assert flops.step_flops(cfg, q, kv, 1) == (
+        8 * (2 * 1000 * 257 + 5.0 * 808) + 2 * 5120 * 151936)

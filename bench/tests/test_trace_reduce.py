@@ -1,9 +1,19 @@
 """Trace reduction: busy time is the union of device op intervals inside the
 traced window, kernel time sums that kernel's events, and each idle gap is
-labelled by the innermost host span in flight."""
+labelled by the innermost host span in flight; the harness hands the
+reduction every kernel its per-layer readers name."""
+import json
+import sys
+import types
 from pathlib import Path
 
+import pytest
+
+from bench import run
 from bench import trace_reduce as T
+from bench.tests.tiny import ROOT
+
+SMALL = Path(__file__).parent / "data" / "small.xplane.pb"
 
 
 def events():
@@ -51,8 +61,7 @@ def test_recorded_chip_trace():
     # bench/tests/data/small.xplane.pb: record_trace.py on one v5e; three
     # steps, each a 20 ms host span with the device idle, then a matmul and
     # the mixed paged attention kernel
-    r = T.reduce(T.load(Path(__file__).parent / "data" / "small.xplane.pb"),
-                 kernels=("mixed_paged_attention",))
+    r = T.reduce(T.load(SMALL), kernels=("mixed_paged_attention",))
     assert r["devices"] == 1 and 0.06 < r["window_s"] < 0.2
     assert 0 < r["busy_s"] < 0.1 * r["window_s"]
     assert 0 < r["kernel_s"]["mixed_paged_attention"] < r["busy_s"]
@@ -60,3 +69,31 @@ def test_recorded_chip_trace():
     assert any(n.startswith("mixed_paged_attention") for n in names)
     label, idle = r["idle_gaps"][0]
     assert label == "sched.next_step" and idle > 0.06
+
+
+def test_kernel_time_by_reader_on_recorded_chip_trace():
+    # the benchmark's per-layer readers name the mixed kernel alone (the
+    # roofline reader's KERNEL), and its device seconds on the recorded
+    # trace are those the harness's former fixed kernel list gave
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    kernels = run.reader_kernels([m["name"] for m in bench["per_layer"]])
+    assert kernels == ("mixed_paged_attention",)
+    r = T.reduce(T.load(SMALL), kernels=kernels)
+    assert r["kernel_s"] == {"mixed_paged_attention": pytest.approx(48159e-9, abs=1e-15)}
+
+
+def test_a_new_reader_names_its_own_kernels(monkeypatch):
+    stub = types.ModuleType("bench.metrics.expert_matmul_roofline")
+    stub.KERNEL = ("expert_matmul", "router")
+    monkeypatch.setitem(sys.modules, stub.__name__, stub)
+    kernels = run.reader_kernels(["device_idle_share", "mixed_paged_attention_roofline",
+                                  "expert_matmul_roofline"])
+    assert kernels == ("mixed_paged_attention", "expert_matmul", "router")
+    ev = events()
+    ev.device_ops["/device:TPU:0"] += [(72, 88, "expert_matmul.7"),
+                                       (88, 94, "router_fusion.2")]
+    r = T.reduce(ev, kernels=kernels)
+    # summed over the two devices, over two
+    assert r["kernel_s"] == pytest.approx({"mixed_paged_attention": 15e-9,
+                                           "expert_matmul": 8e-9, "router": 3e-9},
+                                          abs=1e-18)

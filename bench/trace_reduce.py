@@ -36,7 +36,11 @@ def load(path) -> Events:
     """Read the trace file into plain intervals (nanoseconds)."""
     from jax.profiler import ProfileData
 
-    pd = ProfileData.from_file(str(path))
+    return events(ProfileData.from_file(str(path)), path)
+
+
+def events(pd, path="the trace") -> Events:
+    """Plain intervals (nanoseconds) of a parsed trace (``ProfileData``)."""
     ops: Dict[str, List[Tuple[int, int, str]]] = {}
     spans: List[Tuple[int, int, str]] = []
     for plane in pd.planes:
@@ -96,7 +100,8 @@ def _label(spans: Sequence[Tuple[int, int, str]], t: int) -> str:
 def reduce(ev: Events, kernels: Sequence[str] = (), top: int = 10) -> dict:
     """busy_s (mean over devices), window_s, kernel_s {name: summed device
     seconds over devices / devices}, device_ops [[name, s]] and idle_gaps
-    [[host span, s]] (summed, largest first, device 0)."""
+    [[host span, s]] (summed, largest first, device 0). An operation counts
+    toward each name in ``kernels`` that its own name contains."""
     w = ev.window
     n = max(len(ev.device_ops), 1)
     busy = 0

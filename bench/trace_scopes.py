@@ -44,9 +44,10 @@ def module_name(text: str) -> str:
 def load(path) -> ScopedEvents:
     from jax.profiler import ProfileData
 
-    ev = T.load(path)
+    pd = ProfileData.from_file(str(path))
+    ev = T.events(pd, path)
     modules: Dict[str, List[Span]] = {}
-    for plane in ProfileData.from_file(str(path)).planes:
+    for plane in pd.planes:
         if plane.name in ev.device_ops:
             for line in plane.lines:
                 if line.name == MODULES_LINE:
