@@ -11,6 +11,9 @@ from repro.configs import deepseek_v2_236b  # noqa: F401
 from repro.configs import qwen3_moe_30b_a3b  # noqa: F401
 from repro.configs import whisper_small  # noqa: F401
 
+# benchmark configurations beyond the assigned set
+from repro.configs import deepseek_v2_lite  # noqa: F401
+
 # the paper's own evaluation models
 from repro.configs import llama3_1_8b  # noqa: F401
 from repro.configs import llama3_1_70b  # noqa: F401

@@ -26,6 +26,23 @@ class LayerSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of the rotary frequencies (DeepSeek-V2's
+    ``rope_scaling`` with ``type`` yarn): pairs rotating faster than
+    ``beta_fast`` turns over ``original_max_position_embeddings`` keep their
+    frequency, pairs slower than ``beta_slow`` turns are divided by
+    ``factor``, the pairs between ramp linearly; the softmax scale is
+    multiplied by ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     # -- identity ----------------------------------------------------------
     name: str
@@ -43,6 +60,7 @@ class ModelConfig:
 
     # -- attention flavor --------------------------------------------------
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None  # YaRN (deepseek-v2-lite)
     rotary_pct: float = 1.0  # fraction of head_dim that is rotated (chatglm: 0.5)
     qk_norm: bool = False  # per-head RMSNorm on q and k (qwen3)
     qkv_bias: bool = False  # qwen2 / chatglm3
@@ -52,7 +70,7 @@ class ModelConfig:
 
     # -- MLA (deepseek-v2) ---------------------------------------------------
     mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0  # 0: one direct query projection, no query norm
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -154,6 +172,16 @@ class ModelConfig:
         return self.n_heads * self.head_dim
 
     @property
+    def mla_q_params(self) -> int:
+        """MLA query projection weights: the low-rank pair through
+        ``q_lora_rank`` (its norm scale not counted), or one direct matrix
+        where the rank is 0."""
+        qk = self.n_heads * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+        if self.q_lora_rank:
+            return self.d_model * self.q_lora_rank + self.q_lora_rank * qk
+        return self.d_model * qk
+
+    @property
     def kv_bytes_per_token_layer(self) -> int:
         """bf16 KV bytes one attention layer stores per token (paper §II math)."""
         if self.mla:
@@ -187,8 +215,7 @@ class ModelConfig:
         d = self.d_model
         if spec.mixer == "attn":
             if self.mla:
-                qk_head = self.qk_nope_head_dim + self.qk_rope_head_dim
-                n = d * self.q_lora_rank + self.q_lora_rank * self.n_heads * qk_head
+                n = self.mla_q_params
                 n += d * (self.kv_lora_rank + self.qk_rope_head_dim)
                 n += self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
                 n += self.n_heads * self.v_head_dim * d

@@ -57,7 +57,7 @@ def reduce_config(cfg: ModelConfig, *, periods: int = 1, vocab: int = 256) -> Mo
             n_heads=4,
             n_kv_heads=4,
             head_dim=16,
-            q_lora_rank=32,
+            q_lora_rank=32 if cfg.q_lora_rank else 0,
             kv_lora_rank=16,
             qk_nope_head_dim=16,
             qk_rope_head_dim=8,

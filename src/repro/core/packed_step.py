@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.models import moe
-from repro.models.attention import NEG_INF, softcap
+from repro.models.attention import NEG_INF, mla_scale, softcap
 from repro.models.layers import apply_rope, dense, ffn, rms_norm
 from repro.models.model import Model
 
@@ -50,8 +50,12 @@ from repro.models.model import Model
 # time can be summed by part of the step (``Engine.step_op_scopes``).
 # ``kv_pool`` is the KV pool's movement: a layer's slice out of the scanned
 # pool, the scatter of the step's K/V and the write-back.
+# ``moe_route`` and ``moe_experts`` are a MoE layer's routing (router,
+# top-k, the routed rows' tile-aligned layout, their gather and weighted
+# sum back) and its grouped expert matmuls; the shared experts and dense
+# FFNs stay under ``mlp``.
 STEP_SCOPES = ("embed", "qkv", "kv_pool", "attention", "o_proj", "mlp",
-               "lm_head")
+               "moe_route", "moe_experts", "lm_head")
 
 
 def supports_packed(cfg: ModelConfig) -> bool:
@@ -64,14 +68,15 @@ class PagedView:
     page pool.
 
     The cache arrays are head-major (n_pages, KV, page_size, d) pools
-    (MLA latents: (n_pages, page_size, L)) — there is no dense slot axis.
+    (MLA latents: (n_pages, page_size, L), L padded to whole lane tiles) —
+    there is no dense slot axis.
     ``block_tables`` is the engine's device mirror of the allocator's block
     tables — one row per scheduler slot (incl. the scratch
     slot), carrying the allocator's **actual** page ids, already sliced to
     ``nb`` columns where ``nb * page_size`` covers the longest live context
     this step. Dead entries point at the scratch page, so every id is a
-    valid pool index for the paths that gather whole tables (the jnp
-    oracle, the latent branch); the kernel never reads them.
+    valid pool index for the jnp oracles, which gather whole tables; the
+    kernels never read them.
 
     The segment layout (``cu_q_lens`` / ``kv_lens`` / ``seg_slots``) carries
     the step's mixed batch: decode rows first (one 1-token segment each),
@@ -88,10 +93,6 @@ class PagedView:
     seg_slots: Optional[jax.Array] = None  # (S,) int32 owning slot per segment
     q_block: int = 1  # static pow2 q-block rows for the mixed kernel
 
-    def row_tables(self, slots: jax.Array) -> jax.Array:
-        """Per-row tables: each packed row inherits its slot's table."""
-        return self.block_tables[slots]
-
     def seg_tables(self) -> jax.Array:
         """Per-segment tables: each mixed-batch segment reads through the
         table of the slot that owns it."""
@@ -104,12 +105,15 @@ class PagedView:
         the tables at plan time, so the target pages always exist.
 
         GQA values (N, KV, d) go into a head-major (P, KV, page, d) pool;
-        MLA latent values (N, L) into a (P, page, L) pool, which has no
-        head axis."""
+        MLA latent values (N, L) into the leading L lanes of a (P, page,
+        W >= L) pool, which has no head axis. The rest of a latent row is
+        written as zeros: a whole-row write is one scatter, where a
+        partial one compiles to a loop over the rows."""
         pages = self.block_tables[slots, positions // self.page_size]
         offsets = positions % self.page_size
         if values.ndim == 3:
             return pool.at[pages, :, offsets].set(values.astype(pool.dtype))
+        values = jnp.pad(values, ((0, 0), (0, pool.shape[-1] - values.shape[-1])))
         return pool.at[pages, offsets].set(values.astype(pool.dtype))
 
 
@@ -190,12 +194,17 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
     N, _ = x.shape
     H = cfg.n_heads
     nope, rope, vh = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = mla_scale(cfg)
     with jax.named_scope("qkv"):
         q_nope, q_rope, ckv, krope = _mla_qkv_rope(
             p, cfg, x[:, None, :], positions[:, None], inv_freq)
         q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]  # (N,H,*)
         ckv, krope = ckv[:, 0], krope[:, 0]  # (N,L), (N,rope)
+        # absorbed form: queries into the latent space through W_uk, and
+        # (below, under o_proj) latent outputs out through W_uv
+        w_up = p["kv_up"]["w"].reshape(cfg.kv_lora_rank, H, nope + vh)
+        w_uk, w_uv = w_up[..., :nope], w_up[..., nope:]
+        q_eff = jnp.einsum("nhp,lhp->nhl", q_nope, w_uk.astype(x.dtype))
 
     with jax.named_scope("kv_pool"):
         if paged is not None:
@@ -207,36 +216,38 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
     new_cache = {"ckv": cc, "krope": cr}
 
     with jax.named_scope("attention"):
-        w_up = p["kv_up"]["w"].reshape(cfg.kv_lora_rank, H, nope + vh)
-        w_uk, w_uv = w_up[..., :nope], w_up[..., nope:]
-        q_eff = jnp.einsum("nhp,lhp->nhl", q_nope, w_uk.astype(x.dtype))
         if paged is not None:
-            # ragged block-table gather of the latent page pool, bounded to
-            # the live context (nb pages) — the MLA analogue of the paged
-            # GQA path
-            tabs = paged.row_tables(slots)  # (N, nb)
-            nb = tabs.shape[1]
-            Sr = nb * paged.page_size
-            c = cc[tabs].reshape(N, Sr, cfg.kv_lora_rank).astype(x.dtype)
-            kr = cr[tabs].reshape(N, Sr, rope).astype(x.dtype)
-            k_pos = jnp.arange(Sr)[None, :]
+            # one mixed-batch call over the step's segments, absorbed form:
+            # each segment's latent and rope pages are read once per chunk
+            from repro.kernels.paged_attention import ragged_mixed_mla_attention
+
+            o_lat = ragged_mixed_mla_attention(
+                q_eff, q_rope.astype(x.dtype), cc, cr,
+                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(),
+                qb=paged.q_block, scale=scale,
+                use_kernel=paged.use_kernel, interpret=paged.interpret,
+            ).astype(x.dtype)
         else:
-            Sr = cc.shape[1]
             c = cc[slots].astype(x.dtype)  # (N,S,L)
             kr = cr[slots].astype(x.dtype)  # (N,S,rope)
-            k_pos = jnp.arange(Sr)[None, :]
-        s = jnp.einsum("nhl,nsl->nhs", q_eff, c) + jnp.einsum("nhr,nsr->nhs", q_rope, kr)
-        s = s.astype(jnp.float32) * scale
-        ok = k_pos <= positions[:, None]
-        s = jnp.where(ok[:, None, :], s, NEG_INF)
-        probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        o_lat = jnp.einsum("nhs,nsl->nhl", probs, c)
-        o = jnp.einsum("nhl,lhv->nhv", o_lat, w_uv.astype(x.dtype)).reshape(N, H * vh)
+            k_pos = jnp.arange(cc.shape[1])[None, :]
+            s = jnp.einsum("nhl,nsl->nhs", q_eff, c) + jnp.einsum("nhr,nsr->nhs", q_rope, kr)
+            s = s.astype(jnp.float32) * scale
+            ok = k_pos <= positions[:, None]
+            s = jnp.where(ok[:, None, :], s, NEG_INF)
+            probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            o_lat = jnp.einsum("nhs,nsl->nhl", probs, c)
     with jax.named_scope("o_proj"):
+        o = jnp.einsum("nhl,lhv->nhv", o_lat, w_uv.astype(x.dtype)).reshape(N, H * vh)
         return dense(p["wo"], o), new_cache
 
 
-def _packed_layer(p, cfg, spec, x, slots, positions, cache, inv_freq, paged=None):
+def _packed_layer(p, cfg, spec, x, slots, positions, cache, inv_freq, paged=None,
+                  valid=None, layer=None):
+    """One layer over the packed rows: (x, new cache, routed rows per
+    expert (n_experts,) for a MoE layer, else None). ``valid`` marks the
+    real rows the experts route; with ``layer`` the experts' weights are
+    the whole stack over layers (``_split_experts``)."""
     with jax.named_scope("qkv"):
         hn = rms_norm(p["norm1"], x, cfg.norm_eps)
     if cfg.mla:
@@ -249,23 +260,48 @@ def _packed_layer(p, cfg, spec, x, slots, positions, cache, inv_freq, paged=None
         if cfg.post_norm:
             y = rms_norm(p["post_norm1"], y, cfg.norm_eps)
         x = x + y
+    rows = None
     if spec.ffn != "none":
         with jax.named_scope("mlp"):
             hn = rms_norm(p["norm2"], x, cfg.norm_eps)
-            if spec.ffn == "dense":
+        if spec.ffn == "dense":
+            with jax.named_scope("mlp"):
                 y = ffn(p["ffn"], hn, cfg.act, cfg.glu)
-            else:
-                y, _ = moe.moe_apply(p["ffn"], cfg, hn[None])  # (1,N,d)
-                y = y[0]
+        else:  # routed experts under their own scopes, not under mlp
+            y, rows = moe.moe_routed(
+                p["ffn"], cfg, hn, valid, layer=layer,
+                use_kernel=paged is not None and paged.use_kernel,
+                interpret=paged is not None and paged.interpret)
+            if cfg.n_shared_experts:
+                with jax.named_scope("mlp"):
+                    y = y + ffn(p["ffn"]["shared"], hn, cfg.act, cfg.glu)
+        with jax.named_scope("mlp"):
             if cfg.post_norm:
                 y = rms_norm(p["post_norm2"], y, cfg.norm_eps)
             x = x + y
-    return x, new_cache
+    return x, new_cache, rows
+
+
+def _split_experts(periods):
+    """(the scanned layers' parameters without their routed experts, {period
+    position: experts stacked over periods}). The experts stay out of the
+    scan's per-layer slices: the grouped matmul reads its layer from the
+    whole stack, so no layer's experts are copied out each step."""
+    rest, experts = {}, {}
+    for k, p in periods.items():
+        if "experts" in p.get("ffn", {}):
+            experts[k] = p["ffn"]["experts"]
+            p = {**p, "ffn": {n: v for n, v in p["ffn"].items() if n != "experts"}}
+        rest[k] = p
+    return rest, experts
 
 
 def packed_step(model: Model, params, cache, tokens, slots, positions,
                 paged: Optional[PagedView] = None):
-    """tokens/slots/positions: (N,) -> (logits (N, vocab), new cache).
+    """tokens/slots/positions: (N,) -> (logits (N, vocab), new cache,
+    expert rows). ``expert rows`` is None for a model without MoE layers,
+    else the (MoE layers, n_experts) int32 count of real rows routed to each
+    expert this step (prefix layers first, then the scanned ones).
 
     Padding rows point at a scratch slot whose table names only the scratch
     page (paged mode) or at an extra dense cache row (dense mode); their
@@ -283,41 +319,53 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
         if cfg.scale_embeddings:
             x = x * jnp.asarray(cfg.d_model**0.5, model.dtype)
 
-    new_prefix = []
+    # the real rows (paged: rows past the last segment are padding)
+    valid = None if paged is None else jnp.arange(x.shape[0]) < paged.cu_q_lens[-1]
+    new_prefix, rows = [], []
     for i in range(cfg.n_prefix_layers):
-        x, nc = _packed_layer(
+        x, nc, r = _packed_layer(
             params["stack"]["prefix"][i], cfg, cfg.layer_specs[i], x, slots, positions,
-            cache["prefix"][i], model.inv_freq, paged=paged,
+            cache["prefix"][i], model.inv_freq, paged=paged, valid=valid,
         )
         new_prefix.append(nc)
+        rows += [] if r is None else [r[None]]
 
     # the layer-stacked cache rides the scan CARRY (not xs -> ys): each
     # period reads its slice and writes it back in place, so a donated cache
     # is updated without a second full-size copy of the pool
+    scanned, experts = _split_experts(params["stack"]["periods"])
+
     def body(carry, xs):
         x, periods = carry
         p_period, layer = xs
         with jax.named_scope("kv_pool"):
             cache_period = jax.tree.map(lambda c: c[layer], periods)
-        new_cache = {}
+        new_cache, period_rows = {}, []
         for i in range(cfg.scan_period):
-            x, nc = _packed_layer(
-                p_period[str(i)], cfg, cfg.period_specs[i], x, slots, positions,
-                cache_period[str(i)], model.inv_freq, paged=paged,
+            p = p_period[str(i)]
+            if str(i) in experts:
+                p = {**p, "ffn": {**p["ffn"], "experts": experts[str(i)]}}
+            x, nc, r = _packed_layer(
+                p, cfg, cfg.period_specs[i], x, slots, positions,
+                cache_period[str(i)], model.inv_freq, paged=paged, valid=valid,
+                layer=layer if str(i) in experts else None,
             )
             new_cache[str(i)] = nc
+            period_rows += [] if r is None else [r]
         with jax.named_scope("kv_pool"):
             periods = jax.tree.map(
                 lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
                 periods, new_cache)
-        return (x, periods), None
+        return (x, periods), jnp.stack(period_rows) if period_rows else None
 
     if cfg.n_periods:
         with jax.named_scope("kv_pool"):  # the index only selects pool layers
             layers = jnp.arange(cfg.n_periods)
-        (x, new_periods), _ = jax.lax.scan(
-            body, (x, cache["periods"]), (params["stack"]["periods"], layers),
+        (x, new_periods), scan_rows = jax.lax.scan(
+            body, (x, cache["periods"]), (scanned, layers),
         )
+        if scan_rows is not None:  # (periods, MoE layers per period, E)
+            rows.append(scan_rows.reshape(-1, scan_rows.shape[-1]))
     else:
         new_periods = cache["periods"]
 
@@ -326,4 +374,5 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
         w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = softcap((x @ w.astype(x.dtype)).astype(jnp.float32),
                          cfg.final_logit_softcap)
-    return logits, {"prefix": new_prefix, "periods": new_periods}
+    return (logits, {"prefix": new_prefix, "periods": new_periods},
+            jnp.concatenate(rows) if rows else None)

@@ -233,27 +233,9 @@ def mixed_paged_attention(
     scale = 1.0 / d**0.5
     grid = mixed_grid(S, KV, nb, page)
     bp = kv_block_pages(nb, page)
-
-    cu = cu_q_lens.astype(jnp.int32)
-    q_lens = cu[1:] - cu[:-1]
-    row = jnp.arange(N, dtype=jnp.int32)
-    seg = jnp.searchsorted(cu, row, side="right") - 1  # S for padding rows
-    j = row - cu[jnp.clip(seg, 0, S)]
-
-    # per-segment head-major q-block (S, KV, qb*G, d): segment s's
-    # chunk-local token j lands in rows [j*G, (j+1)*G) of every head's
-    # block; padding rows scatter into the throwaway S-th slot (dropped
-    # below), tail rows past qb are dropped by the scatter's out-of-bounds
-    # semantics
-    q_seg = jnp.zeros((S + 1, qb, KV, G, d), q.dtype)
-    q_seg = q_seg.at[jnp.clip(seg, 0, S), j].set(q)
-    q_seg = q_seg[:S].transpose(0, 2, 1, 3, 4).reshape(S, KV, qb * G, d)
-
-    # whole blocks of table columns; the added columns lie past every
-    # segment's length, so no page is ever fetched through them
-    tables = block_tables.astype(jnp.int32)
-    if grid[2] * bp != nb:
-        tables = jnp.pad(tables, ((0, 0), (0, grid[2] * bp - nb)))
+    q_lens, seg, j = _segment_rows(cu_q_lens, N, S)
+    q_seg = _to_segments(q, seg, j, S, qb)
+    tables = _whole_blocks(block_tables, grid[2] * bp)
 
     kernel = functools.partial(
         _mixed_kernel, scale=scale, window=window, softcap_val=softcap,
@@ -291,10 +273,236 @@ def mixed_paged_attention(
     )(
         q_lens, kv_lens.astype(jnp.int32), tables, q_seg, k_pages, v_pages,
     )
-    # back to flat rows: padding rows clamp into some segment's tail and are
-    # discarded by the caller, like every packed padding row
-    o_r = o_seg.reshape(S, KV, qb, G, d)
-    return o_r[jnp.clip(seg, 0, S - 1), :, jnp.clip(j, 0, qb - 1)]  # (N, KV, G, d)
+    return _from_segments(o_seg, seg, j, qb)  # (N, KV, G, d)
+
+
+def _segment_rows(cu_q_lens, N: int, S: int):
+    """(q_lens (S,), seg (N,), j (N,)): each packed row's segment (S for
+    padding rows past ``cu_q_lens[-1]``) and chunk-local index."""
+    cu = cu_q_lens.astype(jnp.int32)
+    q_lens = cu[1:] - cu[:-1]
+    row = jnp.arange(N, dtype=jnp.int32)
+    seg = jnp.searchsorted(cu, row, side="right") - 1  # S for padding rows
+    return q_lens, seg, row - cu[jnp.clip(seg, 0, S)]
+
+
+def _to_segments(q, seg, j, S: int, qb: int):
+    """Flat rows (N, KV, G, d) -> per-segment head-major q-blocks
+    (S, KV, qb*G, d): segment s's chunk-local token j lands in rows
+    [j*G, (j+1)*G) of every head's block; padding rows scatter into the
+    throwaway S-th slot (dropped here), tail rows past qb are dropped by
+    the scatter's out-of-bounds semantics."""
+    _, KV, G, d = q.shape
+    q_seg = jnp.zeros((S + 1, qb, KV, G, d), q.dtype)
+    q_seg = q_seg.at[jnp.clip(seg, 0, S), j].set(q)
+    return q_seg[:S].transpose(0, 2, 1, 3, 4).reshape(S, KV, qb * G, d)
+
+
+def _from_segments(o_seg, seg, j, qb: int):
+    """Per-segment blocks (S, KV, qb*G, d) back to flat rows (N, KV, G, d):
+    padding rows clamp into some segment's tail and are discarded by the
+    caller, like every packed padding row."""
+    S, KV, rows, d = o_seg.shape
+    o_r = o_seg.reshape(S, KV, qb, rows // qb, d)
+    return o_r[jnp.clip(seg, 0, S - 1), :, jnp.clip(j, 0, qb - 1)]
+
+
+def _whole_blocks(block_tables, cols: int):
+    """The tables padded to ``cols`` columns (whole KV blocks); the added
+    columns lie past every segment's length, so no page is ever fetched
+    through them."""
+    tables = block_tables.astype(jnp.int32)
+    if cols != tables.shape[1]:
+        tables = jnp.pad(tables, ((0, 0), (0, cols - tables.shape[1])))
+    return tables
+
+
+def _mixed_mla_kernel(
+    q_lens_ref,  # scalar prefetch (S,)
+    kv_lens_ref,  # scalar prefetch (S,)
+    tables_ref,  # scalar prefetch (S, nbb * bp)
+    ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
+    c_buf, r_buf, sems, acc_ref, m_ref, l_ref,
+    *, scale, page, bp, g,
+):
+    """``_mixed_kernel`` for latent attention: one KV "head" whose keys are
+    the latent and rope pages side by side and whose values are the latent
+    pages again, so each block's pages are copied once and the latent tile
+    serves both products. Rows are (chunk token, query head) pairs."""
+    s_idx = pl.program_id(0)
+    ib = pl.program_id(2)
+    nbb = pl.num_programs(2)
+    q_len = q_lens_ref[s_idx]
+    kv_len = kv_lens_ref[s_idx]
+    blk = bp * page
+    live_pages = pl.cdiv(kv_len, page)
+    runs = functools.partial(
+        _block_runs, blk=blk, kv_len=kv_len, q_len=q_len, window=None)
+
+    def page_copies(b, slot, start):
+        for i in range(bp):
+            p = b * bp + i
+
+            @pl.when(p < live_pages)
+            def _():
+                pid = tables_ref[s_idx, p]
+                for pool, buf in ((c_hbm, c_buf), (r_hbm, r_buf)):
+                    copy = pltpu.make_async_copy(
+                        pool.at[pid], buf.at[slot, i], sems.at[slot])
+                    if start:
+                        copy.start()
+                    else:
+                        copy.wait()
+
+    @pl.when(ib == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    slot = ib % 2
+    own, nxt = _block_copies(ib, nbb, runs)
+
+    @pl.when(own)
+    def _fetch():
+        page_copies(ib, slot, start=True)
+
+    @pl.when(nxt)
+    def _prefetch():
+        page_copies(ib + 1, 1 - slot, start=True)
+
+    @pl.when(runs(ib))
+    def _compute():
+        page_copies(ib, slot, start=False)
+        k_start = ib * blk
+        c = c_buf[slot].reshape(blk, -1)  # (blk, L) latent keys and values
+        kr = r_buf[slot].reshape(blk, -1)  # (blk, rope)
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql_ref[0], c, nt, preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr, nt, preferred_element_type=jnp.float32)
+             ) * scale  # (QB*G, blk)
+        v = c.astype(jnp.float32)
+        v_row = k_start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(v_row < live_pages * page, v, 0.0)
+
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
+        q_pos = kv_len - q_len + j
+        mask = (k_pos <= q_pos) & (j < q_len)
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, 1, keepdims=True), l_ref.shape)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(ib == nbb - 1)
+    def _finalize():
+        l = l_ref[:, :1]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+
+
+# scoped VMEM for the latent kernel: at chunk 128 and 16 heads a q-block is
+# 2,048 rows of 512 + 64 queries, with an f32 accumulator of 2,048 x 512
+MLA_VMEM_BYTES = 64 * 2**20
+
+
+@functools.partial(jax.jit, static_argnames=("qb", "scale", "interpret"))
+def mixed_paged_mla_attention(
+    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+    *,
+    qb: int,
+    scale: float,
+    interpret: bool = False,
+):
+    """Latent (MLA) attention for the whole packed step, absorbed form: one
+    grid row per segment as in ``mixed_paged_attention``, over the latent
+    page pool.
+
+    q_lat: (N, H, L) absorbed queries ``q_nope W_uk``; q_rope: (N, H, rope);
+    c_pages: (P, page, Wc) latent pool and r_pages: (P, page, Wr) rope-key
+    pool, each holding its L (rope) values in the leading lanes of a whole
+    number of 128-lane tiles, so a page is one tile-aligned copy (the
+    queries are padded with zeros to match, so a pool's tail, zero in the
+    engine's pool, adds nothing); cu_q_lens / kv_lens / block_tables as
+    ``mixed_paged_attention``; ``scale`` the softmax scale. Returns the
+    (N, H, L) latent outputs (softmax over keys of the latent values), for
+    the caller's W_uv."""
+    N, H, L = q_lat.shape
+    S = kv_lens.shape[0]
+    _, page, Wc = c_pages.shape
+    Wr = r_pages.shape[-1]
+    nb = block_tables.shape[1]
+    grid = mixed_grid(S, 1, nb, page)
+    bp = kv_block_pages(nb, page)
+    q_lens, seg, j = _segment_rows(cu_q_lens, N, S)
+    pad = lambda a, w: jnp.pad(a, ((0, 0), (0, 0), (0, w - a.shape[-1])))
+    q_seg = _to_segments(pad(q_lat, Wc)[:, None], seg, j, S, qb)  # (S, 1, qb*H, Wc)
+    r_seg = _to_segments(pad(q_rope, Wr)[:, None], seg, j, S, qb)
+    tables = _whole_blocks(block_tables, grid[2] * bp)
+
+    kernel = functools.partial(_mixed_mla_kernel, scale=scale, page=page, bp=bp, g=H)
+    block = lambda w: pl.BlockSpec((1, None, qb * H, w),
+                                   lambda s, h, ib, qls, kls, tabs: (s, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=grid,
+        in_specs=[
+            block(Wc), block(Wr),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=block(Wc),
+        scratch_shapes=[
+            pltpu.VMEM((2, bp, page, Wc), c_pages.dtype),
+            pltpu.VMEM((2, bp, page, Wr), r_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((qb * H, Wc), jnp.float32),
+            pltpu.VMEM((qb * H, LANES), jnp.float32),
+            pltpu.VMEM((qb * H, LANES), jnp.float32),
+        ],
+    )
+    o_seg = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, 1, qb * H, Wc), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=MLA_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="mixed_paged_mla_attention",
+    )(
+        q_lens, kv_lens.astype(jnp.int32), tables, q_seg, r_seg, c_pages, r_pages,
+    )
+    return _from_segments(o_seg, seg, j, qb)[:, 0, :, :L]  # (N, H, L)
+
+
+def ragged_mixed_mla_attention(
+    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+    *,
+    qb: int,
+    scale: float,
+    use_kernel: bool = False,
+    interpret: bool = False,
+):
+    """Dispatch for latent attention: ``mixed_paged_mla_attention`` on TPU
+    (or interpret mode), the jnp oracle ``kernels.ref.mixed_mla_attention_ref``
+    on CPU."""
+    if use_kernel or interpret:
+        return mixed_paged_mla_attention(
+            q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+            qb=qb, scale=scale, interpret=interpret)
+    from repro.kernels.ref import mixed_mla_attention_ref
+
+    return mixed_mla_attention_ref(
+        q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables, scale=scale)
 
 
 def ragged_mixed_attention(

@@ -103,18 +103,46 @@ def mixed_attention_ref(
     ``cu_q_lens[-1]`` are padding: they read (valid) garbage and are
     discarded by the caller, like every packed padding row.
     """
-    N = q.shape[0]
+    seg, lengths = _row_lengths(cu_q_lens, kv_lens, q.shape[0])
+    return paged_attention_ref(
+        q, k_pages, v_pages, lengths, block_tables[seg],
+        window=window, softcap=softcap,
+    )
+
+
+def _row_lengths(cu_q_lens, kv_lens, N: int):
+    """(segment (N,), keys attended (N,)) of each packed row: row j of a
+    segment attends ``kv_len - q_len + j + 1`` keys (at least 1)."""
     S = kv_lens.shape[0]
     cu = cu_q_lens.astype(jnp.int32)
     row = jnp.arange(N, dtype=jnp.int32)
     seg = jnp.clip(jnp.searchsorted(cu, row, side="right") - 1, 0, S - 1)
     q_len = cu[seg + 1] - cu[seg]
     j = row - cu[seg]
-    lengths = jnp.maximum(kv_lens[seg] - q_len + j + 1, 1)
-    return paged_attention_ref(
-        q, k_pages, v_pages, lengths, block_tables[seg],
-        window=window, softcap=softcap,
-    )
+    return seg, jnp.maximum(kv_lens[seg] - q_len + j + 1, 1)
+
+
+def mixed_mla_attention_ref(q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens,
+                            block_tables, *, scale: float):
+    """Latent (MLA) mixed-batch oracle by per-token expansion, as
+    :func:`mixed_attention_ref`: absorbed queries q_lat (N, H, L) and
+    q_rope (N, H, rope); c_pages (P, page, >= L) and r_pages (P, page,
+    >= rope) holding the latent and rope keys in their leading lanes ->
+    (N, H, L), each row's softmax over its own keys (latent and rope
+    scores) of the latent values, fp32."""
+    N, _, L = q_lat.shape
+    R = q_rope.shape[-1]
+    page = c_pages.shape[1]
+    seg, lengths = _row_lengths(cu_q_lens, kv_lens, N)
+    tabs = block_tables[seg]  # (N, nb): each row's segment's pages
+    S = tabs.shape[1] * page
+    c = c_pages[tabs].reshape(N, S, -1)[..., :L].astype(jnp.float32)
+    kr = r_pages[tabs].reshape(N, S, -1)[..., :R].astype(jnp.float32)
+    s = (jnp.einsum("nhl,nsl->nhs", q_lat.astype(jnp.float32), c)
+         + jnp.einsum("nhr,nsr->nhs", q_rope.astype(jnp.float32), kr)) * scale
+    s = jnp.where((jnp.arange(S)[None, :] < lengths[:, None])[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nhs,nsl->nhl", p, c).astype(q_lat.dtype)
 
 
 def ssd_ref(x, dt, A, Bm, Cm, h0=None):

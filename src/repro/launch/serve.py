@@ -44,7 +44,7 @@ from repro.models import build_model
 from repro.obs.registry import MetricsRegistry
 from repro.obs.trace import TraceRecorder
 from repro.obs.perfetto import dump_json, export_chrome
-from repro.serving.engine import Engine
+from repro.serving.engine import Engine, pool_bytes_per_token_layer
 from repro.serving.metrics import summarize
 from repro.serving.request import Request, State
 from repro.serving.workload import shared_prefix_requests
@@ -73,7 +73,7 @@ def device_kv_pool_blocks(cfg, free_bytes: int, n_rows: int, max_batch: int,
     first: fp32 logits over every packed row (and one working copy), plus
     the one layer's K/V pages the layer scan copies out of the pool per
     page held. 5% stays free for the allocator's own slack."""
-    kv_per_token = cfg.kv_bytes_per_token_layer * cfg.n_attn_layers
+    kv_per_token = pool_bytes_per_token_layer(cfg) * cfg.n_attn_layers
     page_bytes = kv_block * kv_per_token * (1 + 1 / max(cfg.n_attn_layers, 1))
     logits = 2 * n_rows * cfg.vocab_size * 4
     usable = int(free_bytes * 0.95) - logits

@@ -27,6 +27,7 @@ from repro.models.layers import (
     rms_norm,
     rms_norm_init,
     softcap,
+    yarn_mscale,
 )
 
 NEG_INF = -2.0e38
@@ -58,10 +59,15 @@ def _mla_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     ks = jax.random.split(rng, 8)
     d = cfg.d_model
     qk_head = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    if cfg.q_lora_rank:  # low-rank query through a normed latent
+        q = {"q_down": dense_init(ks[0], d, cfg.q_lora_rank, dtype=dtype),
+             "q_norm": rms_norm_init(cfg.q_lora_rank),
+             "q_up": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_head,
+                                dtype=dtype)}
+    else:  # q_lora_rank null (DeepSeek-V2-Lite): one direct projection
+        q = {"wq": dense_init(ks[0], d, cfg.n_heads * qk_head, dtype=dtype)}
     return {
-        "q_down": dense_init(ks[0], d, cfg.q_lora_rank, dtype=dtype),
-        "q_norm": rms_norm_init(cfg.q_lora_rank),
-        "q_up": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_head, dtype=dtype),
+        **q,
         "kv_down": dense_init(ks[2], d, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
                               dtype=dtype),
         "kv_norm": rms_norm_init(cfg.kv_lora_rank),
@@ -230,8 +236,12 @@ def _mla_qkv_rope(params, cfg, x, positions, inv_freq):
     B, T, _ = x.shape
     H = cfg.n_heads
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    ql = rms_norm(params["q_norm"], dense(params["q_down"], x), cfg.norm_eps)
-    q = dense(params["q_up"], ql).reshape(B, T, H, nope + rope)
+    if "wq" in params:
+        q = dense(params["wq"], x)
+    else:
+        q = dense(params["q_up"],
+                  rms_norm(params["q_norm"], dense(params["q_down"], x), cfg.norm_eps))
+    q = q.reshape(B, T, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = apply_rope(q_rope, positions, inv_freq)
 
@@ -242,11 +252,22 @@ def _mla_qkv_rope(params, cfg, x, positions, inv_freq):
     return q_nope, q_rope, ckv, krope
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale: 1/sqrt(nope + rope), times YaRN's mscale(factor,
+    mscale_all_dim) squared where the rope is YaRN-scaled (HF
+    ``DeepseekV2Attention.softmax_scale``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_apply(params, cfg: ModelConfig, x, positions, inv_freq, *, cache, cache_index):
     B, T, _ = x.shape
     H = cfg.n_heads
     nope, rope, vh = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    scale = 1.0 / ((nope + rope) ** 0.5)
+    scale = mla_scale(cfg)
     q_nope, q_rope, ckv, krope = _mla_qkv_rope(params, cfg, x, positions, inv_freq)
 
     w_up_full = params["kv_up"]["w"].reshape(cfg.kv_lora_rank, H, nope + vh)

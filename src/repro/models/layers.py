@@ -99,6 +99,40 @@ def rope_freqs(head_dim: int, rotary_pct: float, theta: float):
     return inv  # (rot_dim//2,)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context extended ``factor`` times."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                          theta: float, max_pos: int):
+    """(low, high) rotation pairs: pairs at or below ``low`` turn more than
+    ``beta_fast`` times over the original context and keep their frequency;
+    pairs at or above ``high`` turn fewer than ``beta_slow`` times and are
+    interpolated (HF ``yarn_find_correction_range``)."""
+    def pair(turns):
+        return dim * math.log(max_pos / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    return max(math.floor(pair(beta_fast)), 0), min(math.ceil(pair(beta_slow)), dim - 1)
+
+
+def yarn_freqs(dim: int, theta: float, rs):
+    """Inverse frequencies of YaRN rope (HF ``DeepseekV2YarnRotaryEmbedding``)
+    for a ``RopeScaling`` ``rs``: plain frequencies up to the low correction
+    pair, frequencies divided by ``rs.factor`` from the high one, a linear
+    ramp between. HF also scales cos/sin by mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim); only configurations where that is 1
+    are accepted."""
+    if yarn_mscale(rs.factor, rs.mscale) != yarn_mscale(rs.factor, rs.mscale_all_dim):
+        raise ValueError("YaRN with mscale != mscale_all_dim scales cos/sin; not supported")
+    extra = rope_freqs(dim, 1.0, theta)
+    low, high = yarn_correction_range(rs.beta_fast, rs.beta_slow, dim, theta,
+                                      rs.original_max_position_embeddings)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return extra / rs.factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x, positions, inv_freq):
     """x: (..., S, H, head_dim); positions: (..., S) int32.
 

@@ -23,7 +23,14 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import encdec, stack
-from repro.models.layers import rms_norm, rms_norm_init, rope_freqs, softcap, truncated_normal
+from repro.models.layers import (
+    rms_norm,
+    rms_norm_init,
+    rope_freqs,
+    softcap,
+    truncated_normal,
+    yarn_freqs,
+)
 
 
 def _positions_from_index(index, B, T):
@@ -60,7 +67,10 @@ class Model:
 
     def __post_init__(self):
         cfg = self.cfg
-        if cfg.mla:
+        if cfg.mla and cfg.rope_scaling is not None:
+            self.inv_freq = yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                       cfg.rope_scaling)
+        elif cfg.mla:
             self.inv_freq = rope_freqs(cfg.qk_rope_head_dim, 1.0, cfg.rope_theta)
         elif cfg.n_heads:
             self.inv_freq = rope_freqs(cfg.head_dim, cfg.rotary_pct, cfg.rope_theta)

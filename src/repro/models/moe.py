@@ -4,9 +4,13 @@ Dispatch is GShard-style scatter/gather with a fixed per-expert capacity so
 the compiled FLOPs scale with top_k (not n_experts) — this keeps the dry-run
 cost_analysis honest about *active* compute, and the (E, C, d) expert batch
 shards cleanly over the "model" (expert-parallel) mesh axis.
+
+Serving takes ``moe_routed`` instead: dropless by construction, and only the
+routed (row, expert) pairs reach the expert matmuls, grouped by expert.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -14,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.ctx import shard_act
-from repro.models.layers import dense_init, ffn, ffn_init
+from repro.models.layers import act_fn, dense_init, ffn, ffn_init
 
 CAPACITY_FACTOR = 1.25
 
@@ -33,6 +37,60 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
         p["shared"] = ffn_init(ks[-1], cfg.d_model, cfg.shared_d_ff, glu=cfg.glu,
                                dtype=dtype)
     return p
+
+
+def moe_routed(params, cfg: ModelConfig, x, valid=None, *, layer=None,
+               use_kernel: bool = False, interpret: bool = False):
+    """The routed experts of the serving step over packed rows ``x`` (N, d):
+    each row goes to its top-k experts of the router's softmax over all
+    ``n_experts``, whatever ``moe_capacity_factor`` says (no row is ever
+    dropped), and only those N x top_k routed rows are computed.
+
+    Under scope ``moe_route``: router, top-k, the tile-aligned layout of the
+    (row, expert) pairs grouped by expert (``kernels.grouped_matmul``), the
+    gather of their rows and the weighted sum back. Under ``moe_experts``:
+    the SwiGLU as two grouped matmuls (gate and up together, then down),
+    which stream each hit expert's weights once; the Pallas kernel with
+    ``use_kernel`` / ``interpret``, else ``jax.lax.ragged_dot``. ``valid``
+    (N,) marks real rows; padding rows join no group. With ``layer`` (a
+    traced index) the experts' weights are stacked over layers and that
+    layer's are used. The shared experts are the caller's. Returns (y (N,
+    d), routed rows per expert (n_experts,) int32)."""
+    from repro.kernels.grouped_matmul import TM, grouped_matmul, tile_layout, tiles_for
+
+    N, d = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    with jax.named_scope("moe_route"):
+        # router scores in float32 (as HF's MoEGate): a top-k boundary
+        # rounded differently picks another expert
+        logits = jnp.dot(x.astype(jnp.float32), params["router"]["w"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        if cfg.norm_topk:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        expert = top_i.reshape(N * K)  # pairs in (row, k) order
+        if valid is not None:  # padding rows join no group
+            expert = jnp.where(jnp.repeat(valid, K), expert, E)
+        tiles = tiles_for(N * K, E)
+        dest, sizes, padded, tile_expert, n_tiles = tile_layout(expert, E, tiles)
+        xs = jnp.zeros((tiles * TM, d), x.dtype).at[dest].set(
+            jnp.repeat(x, K, axis=0), mode="drop")
+    with jax.named_scope("moe_experts"):
+        w = jax.tree.map(lambda a: a.astype(x.dtype), params["experts"])
+        act = act_fn(cfg.act)
+        run = functools.partial(grouped_matmul, tile_expert=tile_expert, n_tiles=n_tiles,
+                                group_rows=padded, layer=layer, use_kernel=use_kernel,
+                                interpret=interpret)
+        if cfg.glu:
+            h = run(xs, (w["gate"]["w"], w["up"]["w"]), act=act)
+        else:
+            h = act(run(xs, (w["up"]["w"],)))
+        ys = run(h, (w["down"]["w"],))
+    with jax.named_scope("moe_route"):
+        keep = dest < tiles * TM
+        yk = jnp.where(keep[:, None], ys[jnp.minimum(dest, tiles * TM - 1)], 0)
+        y = jnp.sum(yk.astype(jnp.float32).reshape(N, K, d) * top_w[..., None], axis=1)
+    return y.astype(x.dtype), sizes
 
 
 def expert_capacity(n_tokens: int, cfg: ModelConfig) -> int:

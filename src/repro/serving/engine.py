@@ -97,11 +97,27 @@ def _page_bucket(n: int) -> int:
     return m
 
 
+def _lane_tiles(width: int) -> int:
+    """A latent pool leaf's width: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+def pool_bytes_per_token_layer(cfg) -> int:
+    """bf16 bytes one attention layer's page pool holds per token: the K/V
+    heads, or (MLA) the latent and rope keys each padded to whole lane
+    tiles (``_init_page_pool``)."""
+    if cfg.mla:
+        return 2 * (_lane_tiles(cfg.kv_lora_rank) + _lane_tiles(cfg.qk_rope_head_dim))
+    return cfg.kv_bytes_per_token_layer
+
+
 def _init_page_pool(model, n_pages: int, page_size: int, dtype):
     """Allocate KV as a physical page pool: every GQA cache leaf becomes a
     head-major (n_pages, heads, page_size, head_dim) array, every MLA latent
-    leaf (n_pages, page_size, width) (period caches keep their leading
-    layer axis). The shapes come from ``model.init_cache`` — one batch row
+    leaf (n_pages, page_size, width) with the width padded to whole
+    128-lane tiles (the 64 rope keys take 128, the tail zero), so that one
+    page is one tile-aligned copy for the latent kernel (period caches keep
+    their leading layer axis). The shapes come from ``model.init_cache`` — one batch row
     of ``n_pages * page_size`` tokens, traced, never allocated — regrouped
     so each page is an independently addressable unit the block tables can
     name in any order."""
@@ -113,7 +129,7 @@ def _init_page_pool(model, n_pages: int, page_size: int, dtype):
         if len(rest) == 2:  # (heads, head_dim): head-major pages
             shape = lead + (n_pages, rest[0], page_size, rest[1])
         else:  # latent width: no head axis
-            shape = lead + (n_pages, page_size) + rest
+            shape = lead + (n_pages, page_size, _lane_tiles(rest[0]))
         return jnp.zeros(shape, leaf.dtype)
 
     return {
@@ -141,6 +157,42 @@ def _put_slot(full, part, slot, axis):
         lambda f, p: jax.lax.dynamic_update_slice_in_dim(f, p.astype(f.dtype), slot, axis=axis),
         full, part,
     )
+
+
+@jax.jit
+def _add_counts(rows, hit, step_rows):
+    return rows + step_rows, hit + jnp.count_nonzero(step_rows).astype(hit.dtype)
+
+
+class _CountedStep:
+    """A MoE model's jitted packed step, called as a dense model's step is:
+    ``(logits, cache)``. The jitted step also returns the (MoE layers,
+    n_experts) count of real rows routed to each expert; each call adds it
+    on the device to ``rows``, and the number of experts it gave a row to
+    (over MoE layers) to ``hit``. Nothing is fetched until ``totals``."""
+
+    def __init__(self, jitted, n_moe: int, n_experts: int):
+        self.jitted = jitted
+        self.__name__ = jitted.__name__  # the compiled module's name
+        self.rows = jnp.zeros((n_moe, n_experts), jnp.int32)
+        self.hit = jnp.zeros((), jnp.int32)
+
+    def __call__(self, *args):
+        logits, cache, step_rows = self.jitted(*args)
+        self.rows, self.hit = _add_counts(self.rows, self.hit, step_rows)
+        return logits, cache
+
+    def totals(self):
+        """(rows routed to each expert of each MoE layer, experts hit summed
+        over steps and MoE layers) since the engine was built."""
+        rows, hit = jax.device_get((self.rows, self.hit))
+        return np.asarray(rows, np.int64), int(hit)
+
+    def lower(self, *args):
+        return self.jitted.lower(*args)
+
+    def _cache_size(self):
+        return self.jitted._cache_size()
 
 
 class Engine:
@@ -286,15 +338,19 @@ class Engine:
                 # seg_slots). ``qb`` — the pow2 q-block rows bucket — is
                 # static so the kernel tiles each segment's queries exactly.
                 def paged_step(p, c, t, s, pos, bt, cq, kl, ss, qb):
-                    return packed_step(
+                    logits, cache, rows = packed_step(
                         model, p, c, t, s, pos,
                         paged=PagedView(bt, page, use_kernel=use_pallas,
                                         cu_q_lens=cq, kv_lens=kl,
                                         seg_slots=ss, q_block=qb),
                     )
+                    return (logits, cache) if rows is None else (logits, cache, rows)
 
                 self._packed = jax.jit(paged_step, static_argnums=(9,),
                                        donate_argnums=(1,))
+                n_moe = sum(1 for sp in model.cfg.layer_specs if sp.ffn == "moe")
+                if n_moe:
+                    self._packed = _CountedStep(self._packed, n_moe, model.cfg.n_experts)
 
                 # mid-block prefix resume: batched copy-on-write page
                 # duplication (gather-then-scatter in ONE compiled call, so
@@ -314,7 +370,7 @@ class Engine:
                 self._copy_pages = jax.jit(copy_pages, donate_argnums=(0,))
             else:
                 def dense_step(p, c, t, s, pos):
-                    return packed_step(model, p, c, t, s, pos)
+                    return packed_step(model, p, c, t, s, pos)[:2]
 
                 self._packed = jax.jit(dense_step)
         else:
@@ -406,9 +462,30 @@ class Engine:
             reg.gauge("kv_pool_peak_used", "pages",
                       "peak pages simultaneously allocated").set(
                           float(self.scheduler.mem.allocator.peak_used_blocks))
+        counts = self.expert_counts()
+        if counts is not None:
+            expert_rows, experts_hit = counts
+            reg.counter("moe.routed_rows", "rows",
+                        "real rows routed to experts, summed over MoE layers "
+                        "and top-k").inc(int(expert_rows.sum()))
+            reg.counter("moe.experts_hit", "experts",
+                        "experts with at least one routed row, summed over "
+                        "steps and MoE layers").inc(experts_hit)
+            for e, n in enumerate(expert_rows.sum(axis=0)):
+                reg.counter(f"moe.expert_rows.{e}", "rows",
+                            f"rows routed to expert {e}, summed over MoE "
+                            "layers").inc(int(n))
         if self.scheduler.injector.enabled:
             self.scheduler.injector.register_metrics(reg)
         self.scheduler.ledger.register_metrics(reg)
+
+    def expert_counts(self):
+        """A MoE model's ``_CountedStep.totals()`` (summed on the device,
+        fetched here); None for a dense model."""
+        step = getattr(self, "_packed", None)  # two-call engines have none
+        if isinstance(step, _CountedStep):
+            return step.totals()
+        return None
 
     def step_op_scopes(self, nb: int, sb: int, qb: int) -> Dict[str, str]:
         """{HLO instruction name: named scope} of the paged packed step

@@ -68,10 +68,8 @@ class Op:
 
 def _attn_weight_bytes(cfg: ModelConfig) -> float:
     if cfg.mla:
-        qk_head = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         n = (
-            cfg.d_model * cfg.q_lora_rank
-            + cfg.q_lora_rank * cfg.n_heads * qk_head
+            cfg.mla_q_params
             + cfg.d_model * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
             + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
             + cfg.n_heads * cfg.v_head_dim * cfg.d_model
@@ -85,9 +83,9 @@ def _attn_weight_bytes(cfg: ModelConfig) -> float:
 def _attn_qkvo_matmuls(cfg: ModelConfig, m: int) -> List[Tuple[int, int, int]]:
     if cfg.mla:
         qk_head = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        return [
-            (m, cfg.d_model, cfg.q_lora_rank),
-            (m, cfg.q_lora_rank, cfg.n_heads * qk_head),
+        q = ([(m, cfg.d_model, cfg.q_lora_rank), (m, cfg.q_lora_rank, cfg.n_heads * qk_head)]
+             if cfg.q_lora_rank else [(m, cfg.d_model, cfg.n_heads * qk_head)])
+        return q + [
             (m, cfg.d_model, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
             (m, cfg.kv_lora_rank, cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
             (m, cfg.n_heads * cfg.v_head_dim, cfg.d_model),

@@ -481,6 +481,73 @@ def test_mixed_block_edges_match_per_token_path(case):
     _check_mixed_matches_per_token_path(c, modes=(tpu_interpret,))
 
 
+# the latent (MLA) kernel: (page, nb, segs (q_len, kv_len), padding rows)
+MLA_CASES = {
+    # decodes beside a chunk; 200 ends mid-block, the chunk starts block 1
+    "decodes_and_chunk": (16, 16, [(1, 200), (1, 17), (6, 133), (0, 0)], 2),
+    # 128 ends on a block edge, 129 one key into the next
+    "block_edges": (16, 16, [(1, 128), (4, 129), (1, 1)], 0),
+    # 4-page blocks of 32-token pages
+    "page32": (32, 8, [(3, 70), (1, 250)], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MLA_CASES))
+def test_mla_kernel_matches_oracle(case):
+    """``mixed_paged_mla_attention`` (interpret mode, and the TPU
+    interpreter that models its page DMAs) and its jnp oracle equal a
+    per-row float64 computation on mixed decode and chunk segments: scores
+    over the latent and rope keys, softmax over the row's own keys, the
+    latent values. Pools carry their keys in the leading lanes of 128, as
+    the engine's do, and table entries past a segment's pages are never
+    fetched."""
+    from repro.kernels.paged_attention import mixed_paged_mla_attention
+
+    page, nb, segs, pad = MLA_CASES[case]
+    rng = np.random.default_rng(1)
+    H, L, R, W, scale = 4, 128, 64, 128, 0.07
+    P = sum(-(-kv // page) for _, kv in segs) + 3
+    perm = rng.permutation(P)
+    tables = np.zeros((len(segs), nb), np.int32)
+    used = 0
+    for s, (_, kv) in enumerate(segs):
+        n = -(-kv // page)
+        tables[s, :n] = perm[used:used + n]
+        used += n
+    q_lens = [q for q, _ in segs]
+    cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+    N = int(cu[-1]) + pad
+    ql = rng.normal(size=(N, H, L)).astype(np.float32)
+    qr = rng.normal(size=(N, H, R)).astype(np.float32)
+    c = rng.normal(size=(P, page, L)).astype(np.float32)
+    kr = np.zeros((P, page, W), np.float32)
+    kr[..., :R] = rng.normal(size=(P, page, R))
+    kv_lens = np.asarray([kv for _, kv in segs], np.int32)
+
+    want = np.zeros((int(cu[-1]), H, L))
+    for s, (q_len, kv) in enumerate(segs):
+        keys = tables[s, :-(-kv // page)] if kv else []
+        ck = c[keys].reshape(-1, L).astype(np.float64)
+        rk = kr[keys].reshape(-1, W)[:, :R].astype(np.float64)
+        for j in range(q_len):
+            n = kv - q_len + j + 1
+            sc = (ql[cu[s] + j] @ ck[:n].T + qr[cu[s] + j] @ rk[:n].T) * scale
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            want[cu[s] + j] = (p / p.sum(-1, keepdims=True)) @ ck[:n]
+
+    args = lambda t: (jnp.asarray(ql), jnp.asarray(qr), jnp.asarray(c), jnp.asarray(kr),
+                      jnp.asarray(cu), jnp.asarray(kv_lens), jnp.asarray(t))
+    got = [ref.mixed_mla_attention_ref(*args(tables), scale=scale)]
+    dead = np.arange(nb)[None, :] >= -(-kv_lens[:, None] // page)
+    poisoned = np.where(dead, P + 1000, tables)  # out of the pool: a read raises
+    for interp in (True, pltpu.InterpretParams()):
+        got.append(mixed_paged_mla_attention(*args(poisoned), qb=8, scale=scale,
+                                             interpret=interp))
+    for g in got:
+        assert g.shape == (N, H, L) and np.all(np.isfinite(np.asarray(g)))
+        np.testing.assert_allclose(np.asarray(g)[:int(cu[-1])], want, rtol=2e-5, atol=2e-5)
+
+
 def test_engine_mixed_swap_oversubscribed_token_identity():
     """Unified path == dense debug fallback == serial reference under swap
     preemption on a genuinely over-subscribed 16-page pool (total demand 21

@@ -79,6 +79,50 @@ def test_mixed_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# DeepSeek-V2-Lite's latent kernel: (qb, S segments, nb table columns)
+MLA_CASES = {"decode": (1, 8, 512), "chunk_nb2048": (128, 8, 2048)}
+
+
+@pytest.mark.parametrize("case", sorted(MLA_CASES))
+def test_mla_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    """16 heads, latent 512 and rope 64 (in a 128-lane pool), 16-token
+    pages: a chunk's q-block is 2,048 rows of 512 + 128 queries."""
+    from repro.kernels.paged_attention import mixed_paged_mla_attention
+
+    qb, S, nb = MLA_CASES[case]
+    N, P, H = 136, 16385, 16
+    f = jax.jit(lambda ql, qr, c, r, cu, kl, bt: mixed_paged_mla_attention(
+        ql, qr, c, r, cu, kl, bt, qb=qb, scale=0.1147))
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    compiled = f.lower(
+        _sds(one_chip, (N, H, 512), bf16), _sds(one_chip, (N, H, 64), bf16),
+        _sds(one_chip, (P, 16, 512), bf16), _sds(one_chip, (P, 16, 128), bf16),
+        _sds(one_chip, (S + 1,), i32), _sds(one_chip, (S,), i32),
+        _sds(one_chip, (S, nb), i32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("glu", [True, False])
+def test_moe_grouped_matmul_compiles_for_v5e(glu, one_chip, no_compile_cache):
+    """DeepSeek-V2-Lite's experts: 64 of 2048 x 1408 in each of 8 stacked
+    layers, the 136 x 6 routed rows of a chunked step in 16-row tiles (111
+    tiles); gate and up together, or down alone."""
+    from repro.kernels.grouped_matmul import moe_grouped_matmul
+
+    L, E, d, f, T = 8, 64, 2048, 1408, 111
+    K, N = (d, f) if glu else (f, d)
+    n_w = 2 if glu else 1
+    g = jax.jit(lambda x, ws, te, n, lay: moe_grouped_matmul(
+        x, ws, te, n, lay, act=jax.nn.silu if glu else None))
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    compiled = g.lower(
+        _sds(one_chip, (T * 16, K), bf16), (_sds(one_chip, (L, E, K, N), bf16),) * n_w,
+        _sds(one_chip, (T,), i32), _sds(one_chip, (1,), i32),
+        _sds(one_chip, (1,), i32)).compile()
+    assert "moe_grouped_matmul" in compiled.as_text()
+
+
 def test_packed_step_fits_one_v5e(one_chip, no_compile_cache):
     """The chip smoke's served step: Llama3.1-8B widths, 16 layers, bf16,
     a 4096-page pool of 16 tokens. The pool is donated and updated in place
