@@ -19,7 +19,10 @@ Two attention realizations:
     row's full padded KV extent: O(N * S_max) bytes/FLOPs regardless of real
     lengths. Kept as reference/fallback.
   * ragged paged (``paged`` given) — KV lives in a *physical page pool*
-    (n_pages, KV, page_size, d) (MLA latents: (n_pages, page_size, L)):
+    (n_pages, KV, page_size, d) (MLA latents: (n_pages, page_size, L)),
+    stacked over the scanned layers, which the scan neither slices out nor
+    writes back: the step writes its pages in place and the kernels read
+    their layer by index.
     both reads AND this step's writes route through the engine's
     block-table mirror, which carries the allocator's real (arbitrary,
     non-contiguous) page ids. A row's token at position p
@@ -48,8 +51,10 @@ from repro.models.model import Model
 # the step's named scopes (``jax.named_scope``): every operation of the
 # compiled step carries one in its metadata, so a profiler trace's device
 # time can be summed by part of the step (``Engine.step_op_scopes``).
-# ``kv_pool`` is the KV pool's movement: a layer's slice out of the scanned
-# pool, the scatter of the step's K/V and the write-back.
+# ``kv_pool`` is the KV pool's movement: the step's K/V written into the
+# stacked pool in place (a head-major GQA pool's touched pages gathered,
+# merged with the step's rows and scattered back whole; a latent pool's
+# rows scattered), no layer's pool copied out or back.
 # ``moe_route`` and ``moe_experts`` are a MoE layer's routing (router,
 # top-k, the routed rows' tile-aligned layout, their gather and weighted
 # sum back) and its grouped expert matmuls; the shared experts and dense
@@ -98,23 +103,40 @@ class PagedView:
         table of the slot that owns it."""
         return self.block_tables[self.seg_slots]
 
-    def scatter(self, pool: jax.Array, slots, positions, values) -> jax.Array:
-        """Write each row's new K/V through the block table: token at
-        logical position p of slot s lands in physical page
+    def scatter(self, pool: jax.Array, slots, positions, values,
+                layer=None) -> jax.Array:
+        """Write each row's new K/V through the block table, into layer
+        ``layer`` of a pool stacked over layers (an unstacked pool without
+        it): token at logical position p of slot s lands in physical page
         ``table[s, p // page]`` at offset ``p % page``. The scheduler grew
         the tables at plan time, so the target pages always exist.
 
-        GQA values (N, KV, d) go into a head-major (P, KV, page, d) pool;
-        MLA latent values (N, L) into the leading L lanes of a (P, page,
-        W >= L) pool, which has no head axis. The rest of a latent row is
-        written as zeros: a whole-row write is one scatter, where a
-        partial one compiles to a loop over the rows."""
+        GQA values (N, KV, d) go into a head-major (L, P, KV, page, d)
+        pool as whole pages: a page of one head is one (page, d) tile, and
+        a row scatter into it makes XLA relayout the whole stack. So the N
+        touched pages are gathered, each takes every row the step writes
+        into it (rows sharing a page thus carry the same page, and of rows
+        sharing a position the last wins), and they are scattered back.
+        MLA latent values (N, L) go into the leading L lanes of an (L, P,
+        page, W >= L) pool, which has no head axis, as a row scatter. The
+        rest of a latent row is written as zeros: a whole-row write is one
+        scatter, where a partial one compiles to a loop over the rows."""
+        if layer is None:
+            return self.scatter(pool[None], slots, positions, values, 0)[0]
         pages = self.block_tables[slots, positions // self.page_size]
         offsets = positions % self.page_size
+        values = values.astype(pool.dtype)
         if values.ndim == 3:
-            return pool.at[pages, :, offsets].set(values.astype(pool.dtype))
+            # writer[n, o]: the last row m writing page pages[n] at offset o
+            rows = jnp.arange(pages.shape[0])
+            hit = ((pages[:, None, None] == pages[None, None, :])
+                   & (offsets[None, None, :] == jnp.arange(self.page_size)[None, :, None]))
+            writer = jnp.max(jnp.where(hit, rows, -1), axis=-1)  # (N, page)
+            new = values[jnp.maximum(writer, 0)].transpose(0, 2, 1, 3)  # (N, KV, page, d)
+            merged = jnp.where((writer >= 0)[:, None, :, None], new, pool[layer, pages])
+            return pool.at[layer, pages].set(merged)
         values = jnp.pad(values, ((0, 0), (0, pool.shape[-1] - values.shape[-1])))
-        return pool.at[pages, offsets].set(values.astype(pool.dtype))
+        return pool.at[layer, pages, offsets].set(values)
 
 
 # ---------------------------------------------------------------------------
@@ -123,7 +145,7 @@ class PagedView:
 
 
 def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache, inv_freq,
-                paged: Optional["PagedView"] = None):
+                paged: Optional["PagedView"] = None, layer=None):
     N, _ = x.shape
     hd = cfg.head_dim
     with jax.named_scope("qkv"):
@@ -150,13 +172,13 @@ def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache
         from repro.kernels.paged_attention import ragged_mixed_attention
 
         with jax.named_scope("kv_pool"):
-            ck = paged.scatter(cache["k"], slots, positions, k)
-            cv = paged.scatter(cache["v"], slots, positions, v)
+            ck = paged.scatter(cache["k"], slots, positions, k, layer)
+            cv = paged.scatter(cache["v"], slots, positions, v, layer)
         with jax.named_scope("attention"):
             o = ragged_mixed_attention(
                 q.reshape(N, KV, G, hd).astype(x.dtype),
                 ck, cv,
-                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(),
+                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(), layer,
                 qb=paged.q_block,
                 window=window, softcap=cfg.attn_logit_softcap,
                 use_kernel=paged.use_kernel, interpret=paged.interpret,
@@ -165,9 +187,8 @@ def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache
             return dense(p["wo"], o), {"k": ck, "v": cv}
 
     with jax.named_scope("kv_pool"):
-        ck = cache["k"].at[slots, positions].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[slots, positions].set(v.astype(cache["v"].dtype))
-    new_cache = {"k": ck, "v": cv}
+        new_cache = _dense_write(cache, layer, slots, positions, {"k": k, "v": v})
+        ck, cv = (_at(new_cache[n], layer) for n in ("k", "v"))
 
     with jax.named_scope("attention"):
         S = ck.shape[1]
@@ -188,7 +209,7 @@ def _packed_gqa(p, cfg: ModelConfig, spec: LayerSpec, x, slots, positions, cache
 
 
 def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
-                paged: Optional["PagedView"] = None):
+                paged: Optional["PagedView"] = None, layer=None):
     from repro.models.attention import _mla_qkv_rope  # same math, (N,1) shaped
 
     N, _ = x.shape
@@ -208,12 +229,12 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
 
     with jax.named_scope("kv_pool"):
         if paged is not None:
-            cc = paged.scatter(cache["ckv"], slots, positions, ckv)
-            cr = paged.scatter(cache["krope"], slots, positions, krope)
+            cc = paged.scatter(cache["ckv"], slots, positions, ckv, layer)
+            cr = paged.scatter(cache["krope"], slots, positions, krope, layer)
+            new_cache = {"ckv": cc, "krope": cr}
         else:
-            cc = cache["ckv"].at[slots, positions].set(ckv.astype(cache["ckv"].dtype))
-            cr = cache["krope"].at[slots, positions].set(krope.astype(cache["krope"].dtype))
-    new_cache = {"ckv": cc, "krope": cr}
+            new_cache = _dense_write(cache, layer, slots, positions,
+                                     {"ckv": ckv, "krope": krope})
 
     with jax.named_scope("attention"):
         if paged is not None:
@@ -223,11 +244,12 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
 
             o_lat = ragged_mixed_mla_attention(
                 q_eff, q_rope.astype(x.dtype), cc, cr,
-                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(),
+                paged.cu_q_lens, paged.kv_lens, paged.seg_tables(), layer,
                 qb=paged.q_block, scale=scale,
                 use_kernel=paged.use_kernel, interpret=paged.interpret,
             ).astype(x.dtype)
         else:
+            cc, cr = (_at(new_cache[n], layer) for n in ("ckv", "krope"))
             c = cc[slots].astype(x.dtype)  # (N,S,L)
             kr = cr[slots].astype(x.dtype)  # (N,S,rope)
             k_pos = jnp.arange(cc.shape[1])[None, :]
@@ -242,20 +264,33 @@ def _packed_mla(p, cfg: ModelConfig, x, slots, positions, cache, inv_freq,
         return dense(p["wo"], o), new_cache
 
 
+def _dense_write(cache, layer, slots, positions, values):
+    """The dense slot cache with each row's values written at (slot,
+    position), in layer ``layer`` of caches stacked over layers (unstacked
+    caches without it)."""
+    at = (slots, positions) if layer is None else (layer, slots, positions)
+    return {n: cache[n].at[at].set(v.astype(cache[n].dtype)) for n, v in values.items()}
+
+
+def _at(c, layer):
+    return c if layer is None else c[layer]
+
+
 def _packed_layer(p, cfg, spec, x, slots, positions, cache, inv_freq, paged=None,
                   valid=None, layer=None):
     """One layer over the packed rows: (x, new cache, routed rows per
     expert (n_experts,) for a MoE layer, else None). ``valid`` marks the
-    real rows the experts route; with ``layer`` the experts' weights are
-    the whole stack over layers (``_split_experts``)."""
+    real rows the experts route. With ``layer`` (a scanned layer) the
+    cache and the experts' weights are the whole stacks over layers
+    (``_split_experts``), of which that layer is read and written."""
     with jax.named_scope("qkv"):
         hn = rms_norm(p["norm1"], x, cfg.norm_eps)
     if cfg.mla:
         y, new_cache = _packed_mla(p["mixer"], cfg, hn, slots, positions, cache, inv_freq,
-                                   paged=paged)
+                                   paged=paged, layer=layer)
     else:
         y, new_cache = _packed_gqa(p["mixer"], cfg, spec, hn, slots, positions, cache, inv_freq,
-                                   paged=paged)
+                                   paged=paged, layer=layer)
     with jax.named_scope("o_proj"):
         if cfg.post_norm:
             y = rms_norm(p["post_norm1"], y, cfg.norm_eps)
@@ -330,33 +365,26 @@ def packed_step(model: Model, params, cache, tokens, slots, positions,
         new_prefix.append(nc)
         rows += [] if r is None else [r[None]]
 
-    # the layer-stacked cache rides the scan CARRY (not xs -> ys): each
-    # period reads its slice and writes it back in place, so a donated cache
-    # is updated without a second full-size copy of the pool
+    # the layer-stacked cache rides the scan CARRY (not xs -> ys) whole:
+    # each period writes the step's K/V into its layer in place and the
+    # kernels read that layer by index, so no layer's pool is sliced out
+    # or written back, and a donated cache is updated without a copy
     scanned, experts = _split_experts(params["stack"]["periods"])
 
     def body(carry, xs):
         x, periods = carry
         p_period, layer = xs
-        with jax.named_scope("kv_pool"):
-            cache_period = jax.tree.map(lambda c: c[layer], periods)
-        new_cache, period_rows = {}, []
+        new_periods, period_rows = {}, []
         for i in range(cfg.scan_period):
             p = p_period[str(i)]
             if str(i) in experts:
                 p = {**p, "ffn": {**p["ffn"], "experts": experts[str(i)]}}
-            x, nc, r = _packed_layer(
+            x, new_periods[str(i)], r = _packed_layer(
                 p, cfg, cfg.period_specs[i], x, slots, positions,
-                cache_period[str(i)], model.inv_freq, paged=paged, valid=valid,
-                layer=layer if str(i) in experts else None,
+                periods[str(i)], model.inv_freq, paged=paged, valid=valid, layer=layer,
             )
-            new_cache[str(i)] = nc
             period_rows += [] if r is None else [r]
-        with jax.named_scope("kv_pool"):
-            periods = jax.tree.map(
-                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0),
-                periods, new_cache)
-        return (x, periods), jnp.stack(period_rows) if period_rows else None
+        return (x, new_periods), jnp.stack(period_rows) if period_rows else None
 
     if cfg.n_periods:
         with jax.named_scope("kv_pool"):  # the index only selects pool layers

@@ -10,14 +10,18 @@ segment actually owns, not with the padded cache extent.
 
 Layouts (grouped-query heads, head-major so the TPU tiles see whole pages):
   q:            (N, KV, G, d)      one query per packed row
-  k/v_pages:    (P, KV, page, d)   page pool, left in HBM; the engine
-                                   allocates KV in this shape directly
-                                   (physically paged — pages are
-                                   relocatable, ids arbitrary) and the
+  k/v_pages:    (L, P, KV, page, d) page pool stacked over layers, left
+                                   in HBM; the engine allocates KV in this
+                                   shape directly (physically paged — pages
+                                   are relocatable, ids arbitrary) and the
                                    tables carry the block allocator's real
                                    page ids. KV sits outside the last two
                                    axes, so one page of one head is one
                                    contiguous (page, d) tile.
+  layer:        int32 scalar       the stack's layer to read, scalar-
+                                   prefetched; without it an unstacked
+                                   (P, KV, page, d) pool is read as a
+                                   stack of one
   cu_q_lens:    (S+1,) int32       row offsets of each segment
   kv_lens:      (S,) int32         keys the segment's last row attends
   block_tables: (S, nb) int32      per-segment page ids, logical order;
@@ -28,8 +32,8 @@ Grid is (S, KV, nb / bp): each step covers one KV block of bp pages of one
 head, bp = 128 // page (at least 1, at most nb; 8 at page 16), so a block's
 scores fill the 128 lanes of a vreg tile whatever the page size. Pages are
 not fetched by BlockSpec index maps: the kernel copies a block's pages
-itself (`make_async_copy` of `pool[table[s, p], h]`) into one slot of a
-2-slot VMEM buffer, starting block ib+1's copies before block ib's compute.
+itself (`make_async_copy` of `pool[layer, table[s, p], h]`) into one slot
+of a 2-slot VMEM buffer, starting block ib+1's copies before block ib's compute.
 `pl.when` guards skip the copies, the compute AND the online-softmax update
 for blocks past a segment's length (and, with `window`, blocks wholly below
 it); inside a live block, pages past the segment's length are not fetched
@@ -107,6 +111,7 @@ def _mixed_kernel(
     q_lens_ref,  # scalar prefetch (S,)
     kv_lens_ref,  # scalar prefetch (S,)
     tables_ref,  # scalar prefetch (S, nbb * bp)
+    layer_ref,  # scalar prefetch (1,)
     q_ref, k_hbm, v_hbm, o_ref,
     k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
     *, scale, window, softcap_val, page, bp, g,
@@ -117,6 +122,7 @@ def _mixed_kernel(
     nbb = pl.num_programs(2)
     q_len = q_lens_ref[s_idx]
     kv_len = kv_lens_ref[s_idx]
+    layer = layer_ref[0]
     blk = bp * page
     live_pages = pl.cdiv(kv_len, page)
     runs = functools.partial(
@@ -133,7 +139,7 @@ def _mixed_kernel(
                 pid = tables_ref[s_idx, p]
                 for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
                     copy = pltpu.make_async_copy(
-                        pool.at[pid, h], buf.at[slot, i], sems.at[slot])
+                        pool.at[layer, pid, h], buf.at[slot, i], sems.at[slot])
                     if start:
                         copy.start()
                     else:
@@ -205,7 +211,7 @@ def _mixed_kernel(
     jax.jit, static_argnames=("qb", "window", "softcap", "interpret")
 )
 def mixed_paged_attention(
-    q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables,
+    q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables, layer=None,
     *,
     qb: int,
     window: Optional[int] = None,
@@ -218,9 +224,11 @@ def mixed_paged_attention(
     per chunk instead of once per token.
 
     q: (N, KV, G, d) flat packed rows, segments contiguous in cu_q_lens
-    order; k/v_pages: (P, KV, page, d); cu_q_lens: (S+1,) int32 row offsets;
-    kv_lens: (S,) int32 keys the segment's last row attends; block_tables:
-    (S, nb) int32 per-segment page ids. ``qb`` is the static q-block row
+    order; k/v_pages: (L, P, KV, page, d) stacked over layers, of which
+    ``layer`` (traced int32 scalar) is read, or (P, KV, page, d) with no
+    ``layer``; cu_q_lens: (S+1,) int32 row offsets; kv_lens: (S,) int32
+    keys the segment's last row attends; block_tables: (S, nb) int32
+    per-segment page ids. ``qb`` is the static q-block row
     count — a pow2 bucket of the longest segment (the engine buckets it
     alongside nb and S so the jit cache stays bounded). Rows past
     cu_q_lens[-1] are padding and come back zero; segments with q_len == 0
@@ -228,7 +236,8 @@ def mixed_paged_attention(
     """
     N, KV, G, d = q.shape
     S = kv_lens.shape[0]
-    page = k_pages.shape[2]
+    k_pages, v_pages, layer = _stacked(k_pages, v_pages, layer)
+    page = k_pages.shape[3]
     nb = block_tables.shape[1]
     scale = 1.0 / d**0.5
     grid = mixed_grid(S, KV, nb, page)
@@ -242,9 +251,9 @@ def mixed_paged_attention(
         page=page, bp=bp, g=G,
     )
     q_spec = pl.BlockSpec((1, None, qb * G, d),
-                          lambda s, h, ib, qls, kls, tabs: (s, h, 0, 0))
+                          lambda s, h, ib, qls, kls, tabs, lay: (s, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
             q_spec,
@@ -271,9 +280,18 @@ def mixed_paged_attention(
         interpret=interpret,
         name="mixed_paged_attention",
     )(
-        q_lens, kv_lens.astype(jnp.int32), tables, q_seg, k_pages, v_pages,
+        q_lens, kv_lens.astype(jnp.int32), tables, layer, q_seg, k_pages, v_pages,
     )
     return _from_segments(o_seg, seg, j, qb)  # (N, KV, G, d)
+
+
+def _stacked(a, b, layer):
+    """(a, b, layer as (1,) int32): pools stacked over layers as given, or
+    unstacked pools (``layer`` None) as a stack of one, read at layer 0 —
+    a leading unit axis, which costs no copy."""
+    if layer is None:
+        a, b, layer = a[None], b[None], 0
+    return a, b, jnp.reshape(layer, (1,)).astype(jnp.int32)
 
 
 def _segment_rows(cu_q_lens, N: int, S: int):
@@ -321,6 +339,7 @@ def _mixed_mla_kernel(
     q_lens_ref,  # scalar prefetch (S,)
     kv_lens_ref,  # scalar prefetch (S,)
     tables_ref,  # scalar prefetch (S, nbb * bp)
+    layer_ref,  # scalar prefetch (1,)
     ql_ref, qr_ref, c_hbm, r_hbm, o_ref,
     c_buf, r_buf, sems, acc_ref, m_ref, l_ref,
     *, scale, page, bp, g,
@@ -334,6 +353,7 @@ def _mixed_mla_kernel(
     nbb = pl.num_programs(2)
     q_len = q_lens_ref[s_idx]
     kv_len = kv_lens_ref[s_idx]
+    layer = layer_ref[0]
     blk = bp * page
     live_pages = pl.cdiv(kv_len, page)
     runs = functools.partial(
@@ -348,7 +368,7 @@ def _mixed_mla_kernel(
                 pid = tables_ref[s_idx, p]
                 for pool, buf in ((c_hbm, c_buf), (r_hbm, r_buf)):
                     copy = pltpu.make_async_copy(
-                        pool.at[pid], buf.at[slot, i], sems.at[slot])
+                        pool.at[layer, pid], buf.at[slot, i], sems.at[slot])
                     if start:
                         copy.start()
                     else:
@@ -415,7 +435,7 @@ MLA_VMEM_BYTES = 64 * 2**20
 
 @functools.partial(jax.jit, static_argnames=("qb", "scale", "interpret"))
 def mixed_paged_mla_attention(
-    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables, layer=None,
     *,
     qb: int,
     scale: float,
@@ -426,8 +446,10 @@ def mixed_paged_mla_attention(
     page pool.
 
     q_lat: (N, H, L) absorbed queries ``q_nope W_uk``; q_rope: (N, H, rope);
-    c_pages: (P, page, Wc) latent pool and r_pages: (P, page, Wr) rope-key
-    pool, each holding its L (rope) values in the leading lanes of a whole
+    c_pages: (L, P, page, Wc) latent pool and r_pages: (L, P, page, Wr)
+    rope-key pool, stacked over layers of which ``layer`` (traced int32
+    scalar) is read, or (P, page, W) each with no ``layer``; each holds
+    its L (rope) values in the leading lanes of a whole
     number of 128-lane tiles, so a page is one tile-aligned copy (the
     queries are padded with zeros to match, so a pool's tail, zero in the
     engine's pool, adds nothing); cu_q_lens / kv_lens / block_tables as
@@ -436,7 +458,8 @@ def mixed_paged_mla_attention(
     the caller's W_uv."""
     N, H, L = q_lat.shape
     S = kv_lens.shape[0]
-    _, page, Wc = c_pages.shape
+    c_pages, r_pages, layer = _stacked(c_pages, r_pages, layer)
+    _, _, page, Wc = c_pages.shape
     Wr = r_pages.shape[-1]
     nb = block_tables.shape[1]
     grid = mixed_grid(S, 1, nb, page)
@@ -449,9 +472,9 @@ def mixed_paged_mla_attention(
 
     kernel = functools.partial(_mixed_mla_kernel, scale=scale, page=page, bp=bp, g=H)
     block = lambda w: pl.BlockSpec((1, None, qb * H, w),
-                                   lambda s, h, ib, qls, kls, tabs: (s, h, 0, 0))
+                                   lambda s, h, ib, qls, kls, tabs, lay: (s, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
             block(Wc), block(Wr),
@@ -479,13 +502,13 @@ def mixed_paged_mla_attention(
         interpret=interpret,
         name="mixed_paged_mla_attention",
     )(
-        q_lens, kv_lens.astype(jnp.int32), tables, q_seg, r_seg, c_pages, r_pages,
+        q_lens, kv_lens.astype(jnp.int32), tables, layer, q_seg, r_seg, c_pages, r_pages,
     )
     return _from_segments(o_seg, seg, j, qb)[:, 0, :, :L]  # (N, H, L)
 
 
 def ragged_mixed_mla_attention(
-    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+    q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables, layer=None,
     *,
     qb: int,
     scale: float,
@@ -494,19 +517,22 @@ def ragged_mixed_mla_attention(
 ):
     """Dispatch for latent attention: ``mixed_paged_mla_attention`` on TPU
     (or interpret mode), the jnp oracle ``kernels.ref.mixed_mla_attention_ref``
-    on CPU."""
+    on CPU, over ``layer`` of stacked pools (or unstacked pools without
+    it)."""
     if use_kernel or interpret:
         return mixed_paged_mla_attention(
-            q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables,
+            q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables, layer,
             qb=qb, scale=scale, interpret=interpret)
     from repro.kernels.ref import mixed_mla_attention_ref
 
+    if layer is not None:
+        c_pages, r_pages = c_pages[layer], r_pages[layer]
     return mixed_mla_attention_ref(
         q_lat, q_rope, c_pages, r_pages, cu_q_lens, kv_lens, block_tables, scale=scale)
 
 
 def ragged_mixed_attention(
-    q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables,
+    q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables, layer=None,
     *,
     qb: int,
     window: Optional[int] = None,
@@ -517,14 +543,17 @@ def ragged_mixed_attention(
     """Dispatch for the unified mixed-batch path: Pallas kernel on TPU (or
     interpret mode), per-token-expansion jnp oracle on CPU. Both read each
     segment's pages bounded to its own context; the kernel additionally reads
-    them once per *chunk* rather than once per token."""
+    them once per *chunk* rather than once per token. ``layer`` selects
+    one layer of pools stacked over layers, as in the kernel."""
     if use_kernel or interpret:
         return mixed_paged_attention(
-            q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables,
+            q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables, layer,
             qb=qb, window=window, softcap=softcap, interpret=interpret,
         )
     from repro.kernels.ref import mixed_attention_ref
 
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     return mixed_attention_ref(
         q, k_pages, v_pages, cu_q_lens, kv_lens, block_tables,
         window=window, softcap=softcap,

@@ -588,8 +588,11 @@ def test_step_op_scopes_name_every_op_of_the_step():
         if re.fullmatch(r"while(/(body|cond)/[a-z_]+)?", path):
             continue
         assert name in scopes, (name, path)
-        if kind in ("dynamic-slice", "dynamic-update-slice") and (
-                "closed_call/kv_pool" in path):
-            assert scopes[name] == "kv_pool"
-            pool_ops += 1
-    assert pool_ops >= 2  # the layer's slice of the pool and its write-back
+        if "closed_call/kv_pool" in path:
+            # the stacked pool is written in place: no layer is sliced out
+            # of it or written back
+            assert kind not in ("dynamic-slice", "dynamic-update-slice"), name
+            if kind == "scatter":
+                assert scopes[name] == "kv_pool"
+                pool_ops += 1
+    assert pool_ops >= 2  # the step's K and V pages written into the stack

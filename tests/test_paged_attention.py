@@ -618,3 +618,123 @@ def test_packed_jit_cache_bounded():
     # far fewer than steps, and bounded regardless of how long we run
     assert eng._packed._cache_size() <= 6
     assert eng._packed._cache_size() < eng.steps_run
+
+
+# ---------------------------------------------------------------------------
+# pools stacked over layers: the kernels read a layer by index and the step
+# writes into its layer in place
+# ---------------------------------------------------------------------------
+
+
+def _stack(rng, pool, layer, n_layers=3):
+    """``pool`` as layer ``layer`` of an (n_layers, ...) stack whose other
+    layers hold different random contents."""
+    out = rng.standard_normal((n_layers,) + pool.shape).astype(pool.dtype)
+    out[layer] = pool
+    return out
+
+
+@pytest.mark.parametrize("interp", ["interpret", "tpu_interpreter"])
+def test_mixed_kernel_reads_one_layer_of_a_stack(interp):
+    """Layer 2 of a 3-layer stack whose layers 0-1 hold other keys and
+    values: the kernel matches the oracle on that layer's pool, and the
+    unstacked form on the same pool gives the same result bit for bit."""
+    from repro.kernels.paged_attention import mixed_paged_attention, ragged_mixed_attention
+
+    KV, G, page, nb, segs, pad, window = BLOCK_EDGE_CASES["padding_segments"]
+    rng = np.random.default_rng(5)
+    c = _mixed_case(rng, KV=KV, G=G, page=page, segs=segs, pad=pad, window=window,
+                    softcap=None, nb=nb)
+    q = jnp.asarray(c["q"]).reshape(-1, KV, G, c["q"].shape[-1])
+    sk, sv = (jnp.asarray(_stack(rng, c[n], 2)) for n in ("pool_k", "pool_v"))
+    meta = (jnp.asarray(c["cu"]), jnp.asarray(c["kv_lens"]), jnp.asarray(c["tables"]))
+    mode = True if interp == "interpret" else pltpu.InterpretParams()
+    got = mixed_paged_attention(q, sk, sv, *meta, jnp.int32(2), qb=c["qb"], interpret=mode)
+    one = mixed_paged_attention(q, sk[2], sv[2], *meta, qb=c["qb"], interpret=mode)
+    want = ref.mixed_attention_ref(q, jnp.asarray(c["pool_k"]), jnp.asarray(c["pool_v"]),
+                                   *meta)
+    oracle = ragged_mixed_attention(q, sk, sv, *meta, jnp.int32(2), qb=c["qb"])
+    n = c["n_real"]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    np.testing.assert_array_equal(np.asarray(oracle), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("interp", ["interpret", "tpu_interpreter"])
+def test_mla_kernel_reads_one_layer_of_a_stack(interp):
+    """The latent kernel on layer 2 of 3-layer latent and rope stacks: the
+    oracle on that layer's pools, and the unstacked form bit for bit."""
+    from repro.kernels.paged_attention import (
+        mixed_paged_mla_attention, ragged_mixed_mla_attention)
+
+    page, nb, segs, pad = MLA_CASES["decodes_and_chunk"]
+    rng = np.random.default_rng(6)
+    H, L, R, W, scale = 4, 128, 64, 128, 0.07
+    P = sum(-(-kv // page) for _, kv in segs) + 3
+    tables = rng.integers(0, P, (len(segs), nb)).astype(np.int32)
+    cu = np.concatenate([[0], np.cumsum([q for q, _ in segs])]).astype(np.int32)
+    N = int(cu[-1]) + pad
+    ql = jnp.asarray(rng.normal(size=(N, H, L)), jnp.float32)
+    qr = jnp.asarray(rng.normal(size=(N, H, R)), jnp.float32)
+    c = rng.normal(size=(P, page, L)).astype(np.float32)
+    kr = np.zeros((P, page, W), np.float32)
+    kr[..., :R] = rng.normal(size=(P, page, R))
+    sc, sr = jnp.asarray(_stack(rng, c, 2)), jnp.asarray(_stack(rng, kr, 2))
+    meta = (jnp.asarray(cu), jnp.asarray([kv for _, kv in segs], jnp.int32),
+            jnp.asarray(tables))
+    mode = True if interp == "interpret" else pltpu.InterpretParams()
+    got = mixed_paged_mla_attention(ql, qr, sc, sr, *meta, jnp.int32(2), qb=8,
+                                    scale=scale, interpret=mode)
+    one = mixed_paged_mla_attention(ql, qr, sc[2], sr[2], *meta, qb=8, scale=scale,
+                                    interpret=mode)
+    want = ref.mixed_mla_attention_ref(ql, qr, jnp.asarray(c), jnp.asarray(kr), *meta,
+                                       scale=scale)
+    oracle = ragged_mixed_mla_attention(ql, qr, sc, sr, *meta, jnp.int32(2), qb=8,
+                                        scale=scale)
+    n = int(cu[-1])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    np.testing.assert_array_equal(np.asarray(oracle), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got)[:n], np.asarray(want)[:n],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["gqa_pages", "latent_rows"])
+def test_scatter_into_stacked_pool_equals_row_scatter(kind):
+    """``PagedView.scatter`` into layer 1 of a 3-layer stack equals writing
+    the rows one by one: a decode row, a chunk whose rows share pages and
+    cross a page boundary, and padding rows (identical, as the engine's
+    are) onto the scratch page. The other layers stay bit-identical, and
+    the unstacked form writes the same layer."""
+    from repro.core.packed_step import PagedView
+
+    rng = np.random.default_rng(7)
+    page, layer, KV, d, W = 4, 1, 2, 8, 16
+    P = 12
+    scratch = P - 1
+    # slot 0 decodes at position 9, slot 1 prefills positions 2..7 (pages 0,
+    # 1 of its table: a boundary at 4), slot 2 is the scratch slot
+    tables = np.asarray([[3, 7, 5], [8, 0, 9], [scratch] * 3], np.int32)
+    slots = np.asarray([0, 1, 1, 1, 1, 1, 1, 2, 2, 2], np.int32)
+    positions = np.asarray([9, 2, 3, 4, 5, 6, 7, 0, 0, 0], np.int32)
+    N = len(slots)
+    shape = (P, KV, page, d) if kind == "gqa_pages" else (P, page, W)
+    pool = rng.standard_normal((3,) + shape).astype(np.float32)
+    values = rng.standard_normal((N, KV, d) if kind == "gqa_pages" else (N, W - 5))
+    values = values.astype(np.float32)
+    values[slots == 2] = values[-1]  # padding rows: one token at one position
+    want = pool.copy()
+    for n in range(N):
+        pg, off = tables[slots[n], positions[n] // page], positions[n] % page
+        if kind == "gqa_pages":
+            want[layer, pg, :, off] = values[n]
+        else:
+            want[layer, pg, off] = np.pad(values[n], (0, W - values.shape[1]))
+    view = PagedView(jnp.asarray(tables), page)
+    args = (jnp.asarray(slots), jnp.asarray(positions), jnp.asarray(values))
+    got = np.asarray(jax.jit(view.scatter)(jnp.asarray(pool), *args, jnp.int32(layer)))
+    np.testing.assert_array_equal(got, want)
+    for other in (0, 2):
+        np.testing.assert_array_equal(got[other], pool[other])
+    one = np.asarray(jax.jit(view.scatter)(jnp.asarray(pool[layer]), *args))
+    np.testing.assert_array_equal(one, want[layer])

@@ -9,6 +9,7 @@ loads the TPU compiler, and every worker collects the same tests.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,24 +124,23 @@ def test_moe_grouped_matmul_compiles_for_v5e(glu, one_chip, no_compile_cache):
     assert "moe_grouped_matmul" in compiled.as_text()
 
 
-def test_packed_step_fits_one_v5e(one_chip, no_compile_cache):
-    """The chip smoke's served step: Llama3.1-8B widths, 16 layers, bf16,
-    a 4096-page pool of 16 tokens. The pool is donated and updated in place
-    (aliased, not copied), and weights + pool + temporaries fit 16 GiB."""
+def _compile_step(one_chip, arch, n_layers, pages, n_slots, chunk, sb, nb):
+    """(compiled, cache): the served paged step of ``arch`` cut to
+    ``n_layers`` at published widths, bf16, over a ``pages``-page pool of 16
+    tokens, with the cache donated as the engine donates it."""
     from repro.configs import get_config
     from repro.configs.reduced import cut_depth
     from repro.core.packed_step import PagedView, packed_step
     from repro.models import build_model
     from repro.serving.engine import _init_page_pool
 
-    model = build_model(cut_depth(get_config("llama3.1-8b"), 16),
+    model = build_model(cut_depth(get_config(arch), n_layers),
                         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     place = lambda t: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
     params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(
-        lambda: _init_page_pool(model, 4097, 16, jnp.bfloat16)))
-    n_slots, chunk, sb, nb = 8, 512, 16, 512
+        lambda: _init_page_pool(model, pages, 16, jnp.bfloat16)))
     i32 = lambda *s: _sds(one_chip, s, jnp.int32)
     step = jax.jit(
         lambda p, c, t, s, pos, bt, cq, kl, ss: packed_step(
@@ -151,10 +151,58 @@ def test_packed_step_fits_one_v5e(one_chip, no_compile_cache):
     n = n_slots + chunk
     compiled = step.lower(params, cache, i32(n), i32(n), i32(n),
                           i32(n_slots + 1, nb), i32(sb + 1), i32(sb), i32(sb)).compile()
+    return compiled, cache
+
+
+def _peak(mem):
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+def test_packed_step_fits_one_v5e(one_chip, no_compile_cache):
+    """The chip smoke's served step: Llama3.1-8B widths, 16 layers, bf16,
+    a 4096-page pool of 16 tokens. The pool is donated and updated in place
+    (aliased, not copied), and weights + pool + temporaries fit 16 GiB."""
+    compiled, cache = _compile_step(one_chip, "llama3.1-8b", 16, 4097, n_slots=8,
+                                    chunk=512, sb=16, nb=512)
     assert "mixed_paged_attention" in compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= pool_bytes
-    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    peak = _peak(mem)
     assert peak < V5E_HBM, peak
+
+
+# the benchmark cells' steps: (arch, layers, pool pages, slots, chunk,
+# segments, table columns)
+CELL_STEPS = {
+    "qwen3-32b-l8": ("qwen3-32b", 8, 7001, 8, 128, 16, 1024),
+    "deepseek-v2-lite-l9": ("deepseek-v2-lite", 9, 16385, 8, 128, 16, 2048),
+}
+# ops that move a whole array: a copy, or a slice out of / an update into it
+_MOVES = re.compile(r"= \w+\[([\d,]+)\][^ ]* (?:[\w-]*(?:copy|dynamic-slice|"
+                    r"dynamic-update-slice)[\w.-]*|fusion\(.*kind=kLoop)")
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_STEPS))
+def test_packed_step_keeps_the_pool_stacked(cell, one_chip, no_compile_cache):
+    """The benchmark cells' steps keep the KV pool stacked over layers: no
+    copy, slice or update-slice has a layer's pool or the stack for its
+    result (the step's K/V are scattered into the stack in place), the
+    temporaries are smaller than one layer's K (latent) pool, the pool
+    stays aliased, and the step fits 16 GiB."""
+    arch, n_layers, pages, n_slots, chunk, sb, nb = CELL_STEPS[cell]
+    compiled, cache = _compile_step(one_chip, arch, n_layers, pages, n_slots, chunk,
+                                    sb, nb)
+    pools = [a.shape for a in jax.tree.leaves(cache)]
+    pool_shapes = {",".join(map(str, s)) for p in pools for s in (p, p[1:])}
+    moved = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if (m := _MOVES.search(line)) and m.group(1) in pool_shapes]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    first = jax.tree.leaves(cache["periods"])[0]  # K, or the latent pool
+    one_layer = first.size // first.shape[0] * first.dtype.itemsize
+    assert mem.temp_size_in_bytes < one_layer, (mem.temp_size_in_bytes, one_layer)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert _peak(mem) < V5E_HBM, _peak(mem)
